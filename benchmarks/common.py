@@ -9,6 +9,10 @@ from collections.abc import Callable
 
 import jax
 
+from repro.launch.cache import enable_compile_cache
+
+enable_compile_cache()
+
 
 def time_fn(fn: Callable, *args, warmup: int = 2, iters: int = 5,
             **kwargs) -> float:

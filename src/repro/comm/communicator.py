@@ -25,7 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 @dataclasses.dataclass
@@ -84,10 +83,10 @@ class Communicator:
         shardings = {k: NamedSharding(self.mesh, P())
                      for k in grad_shapes}
         del bucket_bytes  # bucketing folded into XLA's combiner here
-        f = shard_map(body, mesh=self.mesh,
-                      in_specs=({k: P() for k in grad_shapes},),
-                      out_specs={k: P() for k in grad_shapes},
-                      check_rep=False)
+        f = jax.shard_map(body, mesh=self.mesh,
+                          in_specs=({k: P() for k in grad_shapes},),
+                          out_specs={k: P() for k in grad_shapes},
+                          check_vma=False)
         return jax.jit(f, in_shardings=(shardings,), out_shardings=shardings)
 
 

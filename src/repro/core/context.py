@@ -18,6 +18,7 @@ import dataclasses
 import threading
 from typing import Literal
 
+import jax
 import jax.numpy as jnp
 
 Backend = Literal["cpu", "tpu", "gpu"]
@@ -95,6 +96,27 @@ def get_extension_context(backend: Backend = "cpu", *, type_config: str = "float
         raise ValueError(
             f"unknown type_config {type_config!r}; one of {sorted(POLICIES)}")
     return Context(backend=backend, type_config=type_config, kernels=kernels)
+
+
+def resolve_kernels(mode: str | None = None) -> str | None:
+    """The kernel mode a serving engine runs, from the platform.
+
+    No mode on a TPU resolves to compiled ``"pallas"``; elsewhere ``None``
+    keeps the ambient context (the XLA references by default).
+    ``"pallas"`` off a TPU raises here rather than inside Mosaic, which
+    lowers for TPUs only (CPU runs take ``"pallas_interpret"``).
+    """
+    if mode and mode not in KERNEL_MODES:
+        raise ValueError(f"unknown kernels mode {mode!r}; "
+                         f"one of {KERNEL_MODES}")
+    backend = jax.default_backend()
+    if not mode:
+        return "pallas" if backend == "tpu" else None
+    if mode == "pallas" and backend != "tpu":
+        raise ValueError(f"kernels='pallas' compiles for a TPU; the "
+                         f"{backend!r} backend runs 'pallas_interpret' "
+                         f"or the XLA references")
+    return mode
 
 
 def set_default_context(ctx: Context) -> None:

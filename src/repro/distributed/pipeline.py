@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def spmd_pipeline(stage_fn: Callable, n_stages: int, n_micro: int,
@@ -89,6 +88,6 @@ def make_pipeline_fn(stage_fn: Callable, mesh: Mesh, n_micro: int,
 
     pspec = P(axis)   # prefix spec: leading stage axis on every param leaf
     xspec = P()       # microbatch tensor replicated across the pipe axis
-    f = shard_map(wrapped, mesh=mesh, in_specs=(pspec, xspec),
-                  out_specs=xspec, check_rep=False)
+    f = jax.shard_map(wrapped, mesh=mesh, in_specs=(pspec, xspec),
+                      out_specs=xspec, check_vma=False)
     return jax.jit(f)

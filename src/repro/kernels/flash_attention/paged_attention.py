@@ -6,17 +6,24 @@ The XLA references (:func:`ref.paged_decode_reference` /
 one full extra HBM round-trip per step plus a transient allocation that
 scales with the *worst-case* table width, not the request's actual length.
 
-The kernels here never build that view. The K/V pools stay in HBM
-(``memory_space=ANY``); the page table and per-row lengths ride in as
-scalar-prefetch operands (:class:`pltpu.PrefetchScalarGridSpec`) so block
-ids are known ahead of the grid step, and each step issues
-:func:`pltpu.make_async_copy` DMAs that pull exactly one ``(block_size, D)``
-K and V tile into double-buffered VMEM scratch — the next block's copy is
-in flight while the current block is on the MXU. An online softmax
-(m, l, acc) carried in VMEM scratch across the sequential kv-block grid
-axis reproduces the flash-attention recurrence, and ``pl.when`` skips every
-block at or past the row's valid length — idle rows and short requests cost
-no DMA and no FLOPs, instead of attending to a worst-case-wide gather.
+The kernels here never build that view. The page table and per-row
+positions ride in as scalar-prefetch operands
+(:class:`pltpu.PrefetchScalarGridSpec`), and the K/V BlockSpec index maps
+look each grid step's block id up in the table, so the Pallas pipeline
+DMAs one whole ``(block_size, Hkv, D)`` block per step — every kv head at
+once, each block read from HBM exactly once — while the next block's copy
+is in flight. Block ids past a row's last visible block are clamped to
+that last block; the pipeline skips a copy whose block index did not
+change, so the tail of a short row costs no DMA, and ``pl.when`` skips its
+FLOPs. An online softmax (m, l, acc per kv head) carried in VMEM scratch
+across the sequential kv-block grid axis reproduces the flash-attention
+recurrence.
+
+The block is the pool's own ``(bs, Hkv, D)`` tile, so its last two block
+dims are the array's full dims (legal under the (8, 128) rule for any
+head count and head dim), and the per-head ``(bs, D)`` operands are sliced
+from VMEM, never from HBM: a one-head slice of an HBM block is a
+misaligned DMA that Mosaic rejects.
 
 Both entry points share one kernel:
 
@@ -27,9 +34,9 @@ Both entry points share one kernel:
   row b sees gathered positions ``<= pos[b] + i`` (the chunk-causal mask of
   ``ref.prefill_reference``).
 
-GQA is handled exactly as in :mod:`flash_attention`: the grid runs over KV
-heads and the q BlockSpec index map keeps that head's ``rep`` query heads
-resident, flattened to a ``(C * rep, D)`` MXU operand.
+GQA: the wrapper lays q out head-major as ``(B, Hkv, C * rep, D)`` — each
+kv head's ``rep`` query heads flattened with the chunk into one MXU
+operand, row ``c * rep + r``.
 
 :func:`paged_write` is the fused scatter companion: the *output* BlockSpec
 index map computes each token's ``(block, slot)`` destination from the
@@ -38,19 +45,20 @@ scalar-prefetched table, so the chunk lands directly in the pool
 pool, no materialized scatter indices). Tokens past the row's table width
 are redirected into the garbage block 0, which no valid mask ever reads.
 
-Quantized pools (int8/fp8, see :mod:`repro.kernels.quant`) fuse both
-directions into the same kernels: the walk DMAs each block's
-per-(slot, head) scale vector alongside its K/V tile and dequantizes in
-VMEM right after the waits (one VPU broadcast multiply — no dequantized
-pool copy ever exists in HBM, and the DMA'd bytes are *halved*), while
-``paged_write`` computes the absmax quant inside the scatter body and
-donates pool + scale array through the same index maps.
+Quantized pools (int8/fp8, see :mod:`repro.kernels.quant`): the walk
+pipelines each block's ``(bs, Hkv)`` scale tile next to its K/V block and
+dequantizes in VMEM (one VPU broadcast multiply per head — no dequantized
+pool copy ever exists in HBM, and the DMA'd bytes are *halved*).
+:func:`paged_write` quantizes with the XLA path's own
+:func:`quant.quantize`, lands the payload through the kernel and
+scatters the scale rows in XLA.
 
 Validated against the gather-then-dense references in interpret mode (CPU
-container, block sizes 4/8/16, GQA, ragged lengths); ``interpret=False``
-targets real TPUs. Lengths/pos semantics assume ``lengths >= 1`` for any
-row whose output is consumed (the engine always decodes at ``pos + 1``);
-a length-0 row yields zeros, not the reference's garbage-uniform average.
+container, block sizes 4/8/16, GQA, ragged lengths) and compiled for TPU
+v5e at real widths (``tests/test_tpu_compile.py``). Lengths/pos semantics
+assume ``lengths >= 1`` for any row whose output is consumed (the engine
+always decodes at ``pos + 1``); a length-0 row yields zeros, not the
+reference's garbage-uniform average.
 """
 
 from __future__ import annotations
@@ -64,122 +72,58 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import quant
+from repro.kernels.flash_attention import flash_attention as fa
+from repro.kernels.flash_attention import ref
 
-NEG_INF = -1e30
 
-
-def _paged_attn_kernel(pages_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
-                       bs: int, C: int, rep: int, scale: float,
+def _paged_attn_kernel(pages_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
+                       bs: int, C: int, rep: int, n_kv: int, scale: float,
                        quantized: bool):
-    """One (batch row, kv head, kv block) grid step of the paged walk.
+    """One (batch row, kv block) grid step of the paged walk.
 
     Scratch persists across the innermost (sequential) grid axis: m/l/acc
-    carry the online softmax, k_vmem/v_vmem are the two DMA landing slots.
-    ``pos_ref[b] + C`` is the row's visible-token count — for decode
-    (C = 1, pos = lengths - 1) that is exactly ``lengths[b]``.
-
-    Quantized pools add per-(slot, head) scale vectors that ride the same
-    double-buffer rhythm: each block's ``(bs,)`` scale slice is DMA'd
-    alongside its K/V tile (own landing slots + semaphore) and the dequant
-    is a single VPU multiply right after the waits — the MXU sees the
-    same high-precision operands as the unquantized walk, so the online
-    softmax carry and the chunk-causal mask are untouched.
+    carry the online softmax of every kv head. ``pos_ref[b] + C`` is the
+    row's visible-token count — for decode (C = 1, pos = lengths - 1) that
+    is exactly ``lengths[b]``.
     """
+    del pages_ref
     if quantized:
-        (ks_hbm, vs_hbm, o_ref, m_scr, l_scr, acc_scr,
-         k_vmem, v_vmem, ks_vmem, vs_vmem, sem_s, sem) = rest
+        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
-        o_ref, m_scr, l_scr, acc_scr, k_vmem, v_vmem, sem = rest
+        o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    ki = pl.program_id(1)
+    nk = pl.num_programs(1)
     visible = pos_ref[b] + C          # tokens any query of this row can see
-
-    def block_dma(slot, col, hbm, vmem):
-        # The page-table lookup: scalar-prefetched block id -> HBM tile.
-        blk = pages_ref[b, col]
-        return pltpu.make_async_copy(
-            hbm.at[blk, :, h, :], vmem.at[slot], sem.at[slot])
-
-    def scale_dma(slot, col, hbm, vmem):
-        blk = pages_ref[b, col]
-        return pltpu.make_async_copy(
-            hbm.at[blk, :, h], vmem.at[slot], sem_s.at[slot])
-
-    def start_block(slot, col):
-        block_dma(slot, col, k_hbm, k_vmem).start()
-        block_dma(slot, col, v_hbm, v_vmem).start()
-        if quantized:
-            scale_dma(slot, col, ks_hbm, ks_vmem).start()
-            scale_dma(slot, col, vs_hbm, vs_vmem).start()
 
     @pl.when(ki == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-        @pl.when(visible > 0)
-        def _warm():
-            start_block(0, 0)
+        fa.init_carry(m_scr, l_scr, acc_scr)
 
     @pl.when(ki * bs < visible)
     def _body():
-        # Double buffering: kick off block ki+1 into the other slot before
-        # touching this block's data, so its DMA overlaps our MXU work.
-        # Every started copy is awaited by its own grid step (the prefetch
-        # guard only fires for steps that will run), so no semaphore leaks
-        # across (b, h) rows.
-        @pl.when((ki + 1) * bs < visible)
-        def _prefetch():
-            start_block((ki + 1) % 2, ki + 1)
-
-        slot = ki % 2
-        # wait() only consumes the semaphore + dst shape; src is a dummy.
-        pltpu.make_async_copy(k_hbm.at[0, :, h, :], k_vmem.at[slot],
-                              sem.at[slot]).wait()
-        pltpu.make_async_copy(v_hbm.at[0, :, h, :], v_vmem.at[slot],
-                              sem.at[slot]).wait()
-        if quantized:
-            pltpu.make_async_copy(ks_hbm.at[0, :, h], ks_vmem.at[slot],
-                                  sem_s.at[slot]).wait()
-            pltpu.make_async_copy(vs_hbm.at[0, :, h], vs_vmem.at[slot],
-                                  sem_s.at[slot]).wait()
-
-        q = q_ref[0, :, 0, :, :].astype(jnp.float32).reshape(C * rep, -1)
-        k = k_vmem[slot].astype(jnp.float32)              # (bs, D)
-        v = v_vmem[slot].astype(jnp.float32)
-        if quantized:
-            # dequant in VMEM: one broadcast multiply per tile, fused into
-            # the DMA shadow — never a dequantized pool copy in HBM
-            k = k * ks_vmem[slot][:, None]
-            v = v * vs_vmem[slot][:, None]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-
         # chunk-causal: query i (s-row i * rep + r) sees kpos <= pos + i
         qpos = pos_ref[b] + jax.lax.broadcasted_iota(
             jnp.int32, (C * rep, bs), 0) // rep
         kpos = ki * bs + jax.lax.broadcasted_iota(
             jnp.int32, (C * rep, bs), 1)
-        s = jnp.where(kpos <= qpos, s, NEG_INF)
-
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] \
-            + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        causal = kpos <= qpos
+        for h in range(n_kv):
+            q = q_ref[0, h].astype(jnp.float32)               # (C*rep, D)
+            k = k_ref[0, :, h, :].astype(jnp.float32)         # (bs, D)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if quantized:
+                # dequant in VMEM: one broadcast multiply per tile
+                k = k * ks_ref[0, :, h:h + 1]
+                v = v * vs_ref[0, :, h:h + 1]
+            fa.online_softmax_step(q, k, v, causal, scale, m_scr.at[h],
+                                   l_scr.at[h], acc_scr.at[h])
 
     @pl.when(ki == nk - 1)
     def _fini():
-        denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :, :] = (acc_scr[...] / denom[:, None]) \
-            .astype(o_ref.dtype).reshape(C, rep, -1)
+        for h in range(n_kv):
+            o_ref[0, h] = fa.carry_output(l_scr.at[h], acc_scr.at[h],
+                                          o_ref.dtype)
 
 
 def _paged_walk(q, k_pool, v_pool, pages, pos, *, scale, interpret,
@@ -187,7 +131,7 @@ def _paged_walk(q, k_pool, v_pool, pages, pos, *, scale, interpret,
     """Shared pallas_call builder: q (B, C, Hq, D) through the page table
     with the chunk-causal mask anchored at per-row ``pos``. Quantized
     pools (int8/fp8) pass their (NB, bs, Hkv) scale arrays as extra
-    HBM-resident operands."""
+    pipelined operands."""
     B, C, Hq, D = q.shape
     _, bs, Hkv, _ = k_pool.shape
     MB = pages.shape[1]
@@ -198,51 +142,51 @@ def _paged_walk(q, k_pool, v_pool, pages, pos, *, scale, interpret,
         raise ValueError("k_scale given without v_scale")
     rep = Hq // Hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    qh = q.reshape(B, C, Hkv, rep, D)
+    # head-major q: (B, Hkv, C * rep, D), row c * rep + r
+    qh = (q.reshape(B, C, Hkv, rep, D).transpose(0, 2, 1, 3, 4)
+          .reshape(B, Hkv, C * rep, D))
 
-    in_specs = [
-        pl.BlockSpec((1, C, 1, rep, D),
-                     lambda b, h, ki, *_: (b, 0, h, 0, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),   # K pool stays in HBM
-        pl.BlockSpec(memory_space=pltpu.ANY),   # V pool stays in HBM
-    ]
-    scratch = [
-        pltpu.VMEM((C * rep,), jnp.float32),          # m
-        pltpu.VMEM((C * rep,), jnp.float32),          # l
-        pltpu.VMEM((C * rep, D), jnp.float32),        # acc
-        pltpu.VMEM((2, bs, D), k_pool.dtype),         # K landing slots
-        pltpu.VMEM((2, bs, D), v_pool.dtype),         # V landing slots
-    ]
+    def block_map(b, ki, pages_ref, pos_ref):
+        # the page-table lookup; steps past the row's last visible block
+        # keep that block's index, so the pipeline issues no new copy
+        last = jnp.maximum(pos_ref[b] + C - 1, 0) // bs
+        col = jnp.minimum(jnp.minimum(ki, last), MB - 1)
+        return pages_ref[b, col], 0, 0, 0
+
+    def scale_map(b, ki, pages_ref, pos_ref):
+        return block_map(b, ki, pages_ref, pos_ref)[:3]
+
+    row_spec = pl.BlockSpec((1, Hkv, C * rep, D),
+                            lambda b, ki, *_: (b, 0, 0, 0))
+    kv_spec = pl.BlockSpec((1, bs, Hkv, D), block_map)
+    in_specs = [row_spec, kv_spec, kv_spec]
     operands = [pages, jnp.asarray(pos, jnp.int32), qh, k_pool, v_pool]
     if quantized:
-        in_specs += [
-            pl.BlockSpec(memory_space=pltpu.ANY),     # K scales (HBM)
-            pl.BlockSpec(memory_space=pltpu.ANY),     # V scales (HBM)
-        ]
-        scratch += [
-            pltpu.VMEM((2, bs), k_scale.dtype),       # K scale slots
-            pltpu.VMEM((2, bs), v_scale.dtype),       # V scale slots
-            pltpu.SemaphoreType.DMA((2,)),            # scale DMA sem
-        ]
+        sc_spec = pl.BlockSpec((1, bs, Hkv), scale_map)
+        in_specs += [sc_spec, sc_spec]
         operands += [k_scale, v_scale]
-    scratch += [pltpu.SemaphoreType.DMA((2,))]
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,            # pages, pos
-        grid=(B, Hkv, MB),
+        grid=(B, MB),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, C, 1, rep, D),
-                               lambda b, h, ki, *_: (b, 0, h, 0, 0)),
-        scratch_shapes=scratch,
+        out_specs=row_spec,
+        scratch_shapes=[
+            pltpu.VMEM((Hkv, C * rep, 1), jnp.float32),   # m
+            pltpu.VMEM((Hkv, C * rep, 1), jnp.float32),   # l
+            pltpu.VMEM((Hkv, C * rep, D), jnp.float32),   # acc
+        ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_attn_kernel, bs=bs, C=C, rep=rep,
+        functools.partial(_paged_attn_kernel, bs=bs, C=C, rep=rep, n_kv=Hkv,
                           scale=scale, quantized=quantized),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C, Hkv, rep, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, C * rep, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
-    return out.reshape(B, C, Hq, D)
+    return (out.reshape(B, Hkv, C, rep, D).transpose(0, 2, 1, 3, 4)
+            .reshape(B, C, Hq, D))
 
 
 def paged_decode(q, k_pool, v_pool, pages, lengths, *, scale=None,
@@ -297,24 +241,6 @@ def _paged_write_kernel(pages_ref, pos_ref, new_ref, pool_ref, out_ref):
     out_ref[...] = new_ref[...].astype(out_ref.dtype)
 
 
-def _paged_write_quant_kernel(pages_ref, pos_ref, new_ref, pool_ref,
-                              scale_pool_ref, out_ref, scale_out_ref, *,
-                              qmax: float, integer: bool):
-    # Quant fused into the scatter: per-(token, head) absmax over D on the
-    # VPU, then the same output-index-map landing — op-for-op identical to
-    # quant.quantize so the XLA path writes bit-identical pools.
-    del pages_ref, pos_ref, pool_ref, scale_pool_ref
-    x = new_ref[...].astype(jnp.float32)                  # (1, 1, Hkv, D)
-    amax = jnp.maximum(jnp.max(jnp.abs(x), axis=-1), quant._EPS)
-    # reciprocal multiply, matching quant.quantize (see the note there)
-    s = (amax * (1.0 / qmax)).astype(scale_out_ref.dtype)  # (1, 1, Hkv)
-    q = x / s.astype(jnp.float32)[..., None]
-    if integer:
-        q = jnp.clip(jnp.round(q), -qmax, qmax)
-    out_ref[...] = q.astype(out_ref.dtype)
-    scale_out_ref[...] = s
-
-
 def paged_write(pool, new, pages, pos, *, pool_scale=None,
                 interpret: bool = False):
     """Fused scatter of a (B, C, Hkv, D) chunk into a (NB, bs, Hkv, D)
@@ -325,12 +251,18 @@ def paged_write(pool, new, pages, pos, *, pool_scale=None,
     read-modify-write of untouched blocks.
 
     With ``pool_scale`` (quantized (NB, bs, Hkv) scale array), the chunk
-    is absmax-quantized to the pool dtype *inside* the scatter — both the
-    pool and the scale array are donated outputs and the per-token scale
-    lands through the same index map. Returns ``(pool, pool_scale)``."""
+    is absmax-quantized by :func:`quant.quantize` — the op the XLA path
+    runs, so pools are bit-identical across kernel modes — the payload
+    lands through the kernel, and the per-token scale rows (4 bytes per
+    token and head) scatter in XLA: a one-slot ``(1, Hkv)`` block of the
+    ``(bs, Hkv)`` scale tile is not a legal TPU block. Returns
+    ``(pool, pool_scale)``."""
     NB, bs, Hkv, D = pool.shape
     B, C = new.shape[:2]
     MB = pages.shape[1]
+    pos = jnp.asarray(pos, jnp.int32)
+    if pool_scale is not None:
+        new, new_scale = quant.quantize(new, pool.dtype)
 
     def out_map(b, i, pages_ref, pos_ref):
         p = pos_ref[b] + i
@@ -338,52 +270,25 @@ def paged_write(pool, new, pages, pos, *, pool_scale=None,
         blk = jnp.where(col < MB, pages_ref[b, jnp.minimum(col, MB - 1)], 0)
         return blk, p % bs, 0, 0
 
-    if pool_scale is None:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,            # pages, pos
-            grid=(B, C),
-            in_specs=[
-                pl.BlockSpec((1, 1, Hkv, D), lambda b, i, *_: (b, i, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),  # donated pool (unread)
-            ],
-            out_specs=pl.BlockSpec((1, 1, Hkv, D), out_map),
-        )
-        return pl.pallas_call(
-            _paged_write_kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-            # operand 3 counting the two scalar-prefetch args: (pages, pos,
-            # new, pool) -> pool aliases the single output
-            input_output_aliases={3: 0},
-            interpret=interpret,
-        )(pages, jnp.asarray(pos, jnp.int32), new, pool)
-
-    def scale_map(b, i, pages_ref, pos_ref):
-        p = pos_ref[b] + i
-        col = p // bs
-        blk = jnp.where(col < MB, pages_ref[b, jnp.minimum(col, MB - 1)], 0)
-        return blk, p % bs, 0
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                # pages, pos
+        num_scalar_prefetch=2,            # pages, pos
         grid=(B, C),
         in_specs=[
             pl.BlockSpec((1, 1, Hkv, D), lambda b, i, *_: (b, i, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),   # donated pool (unread)
-            pl.BlockSpec(memory_space=pltpu.ANY),   # donated scales (unread)
+            pl.BlockSpec(memory_space=pl.ANY),     # donated pool (unread)
         ],
-        out_specs=[pl.BlockSpec((1, 1, Hkv, D), out_map),
-                   pl.BlockSpec((1, 1, Hkv), scale_map)],
+        out_specs=pl.BlockSpec((1, 1, Hkv, D), out_map),
     )
-    qd = jnp.dtype(pool.dtype)
-    return pl.pallas_call(
-        functools.partial(_paged_write_quant_kernel, qmax=quant.qmax(qd),
-                          integer=bool(jnp.issubdtype(qd, jnp.integer))),
+    pool = pl.pallas_call(
+        _paged_write_kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-                   jax.ShapeDtypeStruct(pool_scale.shape, pool_scale.dtype)],
-        # operands counting the two scalar-prefetch args: (pages, pos, new,
-        # pool, scales) -> pool and scales alias the two outputs
-        input_output_aliases={3: 0, 4: 1},
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        # operand 3 counting the two scalar-prefetch args: (pages, pos,
+        # new, pool) -> pool aliases the single output
+        input_output_aliases={3: 0},
         interpret=interpret,
-    )(pages, jnp.asarray(pos, jnp.int32), new, pool, pool_scale)
+    )(pages, pos, new, pool)
+    if pool_scale is None:
+        return pool
+    slots = ref.paged_slots(pages, pos, C, bs)
+    return pool, ref.scatter_slots(pool_scale, slots, new_scale)

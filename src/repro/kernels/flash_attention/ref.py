@@ -234,6 +234,33 @@ def gather_pages(pool: jax.Array, pages: jax.Array) -> jax.Array:
     return g.reshape((B, MB * bs) + pool.shape[2:])
 
 
+def paged_slots(pages: jax.Array, pos: jax.Array, C: int,
+                bs: int) -> jax.Array:
+    """Flat pool slot of every token of a (B, C) chunk: token i of row b
+    lands at ``pages[b, p // bs] * bs + p % bs`` with ``p = pos[b] + i``.
+    Positions past the table's last column go to the garbage block 0 —
+    clipping the column instead would overwrite whatever live block sits
+    in the last entry. Returns (B * C,) int32."""
+    MB = pages.shape[1]
+    p = (jnp.asarray(pos, jnp.int32)[:, None]
+         + jnp.arange(C, dtype=jnp.int32)[None, :])
+    col = p // bs
+    blk = jnp.take_along_axis(pages, jnp.clip(col, 0, MB - 1), axis=1)
+    blk = jnp.where(col < MB, blk, 0)
+    return (blk * bs + p % bs).reshape(-1)
+
+
+def scatter_slots(pool: jax.Array, slots: jax.Array,
+                  new: jax.Array) -> jax.Array:
+    """Write a (B, C, ...) chunk into a (num_blocks, block_size, ...) pool
+    (or scale array) at the flat ``slots`` of :func:`paged_slots`."""
+    nb, bs = pool.shape[:2]
+    flat = pool.reshape((nb * bs,) + pool.shape[2:])
+    flat = flat.at[slots].set(
+        new.astype(pool.dtype).reshape((-1,) + pool.shape[2:]))
+    return flat.reshape(pool.shape)
+
+
 def _gather_dequant(pool, scale_arr, pages):
     """Gather a quantized pool + its scales into the dense f32 cache the
     unquantized row would have held — the jnp mirror of the kernel's

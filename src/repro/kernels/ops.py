@@ -2,9 +2,10 @@
 
 Models call these; the active :class:`repro.core.context.Context` decides
 whether the Pallas TPU kernel, its interpret-mode build (CPU validation), or
-the plain-XLA reference executes. The dry-run container always takes the XLA
-path (TPU Pallas cannot lower on CPU backends); real-TPU deployments flip
-``Context.kernels`` to ``"pallas"``.
+the plain-XLA reference executes. The serving engine picks the mode from the
+platform (:func:`repro.core.context.resolve_kernels`): compiled Pallas on a
+TPU, the ambient context elsewhere (the XLA references by default; TPU
+Pallas cannot lower on CPU backends).
 
 Op x mode matrix (which implementation runs, and — for the paged ops —
 where quantized pools (int8/fp8, :mod:`repro.kernels.quant`) convert):
@@ -22,8 +23,9 @@ attention_prefill_paged    gather+dense    gather+dense    paged_prefill
   quant (k/v_scale)        dequant in the  dequant in the  dequant in VMEM
                            gather [#f3]_   gather [#f3]_   post-DMA [#f4]_
 paged_cache_write          jnp scatter     jnp scatter     fused paged_write
-  quant (pool_scale)       jnp quantize +  jnp quantize +  absmax quant in
-                           2-array scatter 2-array scatter the scatter body
+  quant (pool_scale)       jnp quantize +  jnp quantize +  jnp quantize,
+                           2-array scatter 2-array scatter payload fused,
+                                                           scales scattered
 ssd                        ssd_chunked     ssd_chunked     ssd kernel [#f2]_
 ssd_decode_step            jnp             jnp             jnp (elementwise)
 =========================  ==============  ==============  ===================
@@ -34,8 +36,8 @@ ssd_decode_step            jnp             jnp             jnp (elementwise)
 .. [#f3] ``gather_pages`` on the quantized pool + scale array, then one
    broadcast multiply — the dense copy is f32, so the same dense oracle
    applies and XLA-vs-Pallas parity holds at quantized dtypes too.
-.. [#f4] *why VMEM*: dequantizing right after the double-buffered DMA
-   wait means the HBM traffic is the **quantized** bytes (the whole point
+.. [#f4] *why VMEM*: dequantizing right after the block's pipelined DMA
+   means the HBM traffic is the **quantized** bytes (the whole point
    of the scheme — the walk is bandwidth-bound), the dequant multiply
    hides in the next block's DMA shadow, and the MXU sees exactly the
    high-precision operands of the unquantized walk, leaving the online
@@ -47,8 +49,8 @@ scale per written (token slot, kv head) — scale arrays (NB, bs, Hkv)
 alongside each (NB, bs, Hkv, D) pool. Per-*slot* (not per-block) scales
 keep the fused write a pure scatter (no read-modify-write of sibling
 slots) and keep speculative decode bitwise: a stored token's bytes never
-depend on rejected draft tokens sharing its block. The XLA quantize and
-the Pallas write-kernel quantize are op-for-op identical, so pools are
+depend on rejected draft tokens sharing its block. Every mode quantizes
+with the same :func:`repro.kernels.quant.quantize`, so pools are
 bit-identical across modes and spill/fetch round-trips are exact.
 
 Speculative verify steps (PR 6) add **no rows**: a ``(B, 1 + k)`` draft
@@ -83,15 +85,14 @@ paged_cache_write          GSPMD scatter          shard_map over kv heads;
 
 The shard_map body is the *unchanged* single-device kernel: with the pool
 sharded on kv heads, every shard walks the full page table over its local
-``Hkv/tp`` head slice of every block — block ids stay global, the VMEM
-double-buffered DMA walk and fused-scatter donation work per shard exactly
+``Hkv/tp`` head slice of every block — block ids stay global, the
+pipelined block walk and fused-scatter donation work per shard exactly
 as they do on one device.
 """
 
 from __future__ import annotations
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import context as _ctx
@@ -156,10 +157,10 @@ def _tp_heads_call(fn, q, kv_args, rep_args, kv_axes=None):
         # chunks lands each chunk on the shard holding its kv heads.
         kv_specs = tuple(
             _head_spec(a, ax) for a, ax in zip(kv_args, kv_axes))
-        sharded = shard_map(
+        sharded = jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P(None, None, "model", None),) + kv_specs + rep_specs,
-            out_specs=P(None, None, "model", None), check_rep=False)
+            out_specs=P(None, None, "model", None), check_vma=False)
         return sharded(q, *kv_args, *rep_args)
     group = Hq // Hkv
     if group % tp == 0:
@@ -171,15 +172,15 @@ def _tp_heads_call(fn, q, kv_args, rep_args, kv_axes=None):
             out = fn(qg_loc.reshape(B, C, -1, D), *args)
             return out.reshape(qg_loc.shape)
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             _grouped, mesh=mesh,
             in_specs=(P(None, None, None, "model", None),)
             + _repl(*kv_args) + rep_specs,
-            out_specs=P(None, None, None, "model", None), check_rep=False)
+            out_specs=P(None, None, None, "model", None), check_vma=False)
         return sharded(qg, *kv_args, *rep_args).reshape(B, C, Hq, D)
-    sharded = shard_map(fn, mesh=mesh,
-                        in_specs=_repl(q, *kv_args) + rep_specs,
-                        out_specs=P(), check_rep=False)
+    sharded = jax.shard_map(fn, mesh=mesh,
+                            in_specs=_repl(q, *kv_args) + rep_specs,
+                            out_specs=P(), check_vma=False)
     return sharded(q, *kv_args, *rep_args)
 
 
@@ -197,12 +198,12 @@ def _tp_write_call(fn, pool, new, pages, pos, pool_scale=None):
     split = pool.shape[-2] % tp == 0
     kv = _head_spec(pool, -2) if split else P()
     if pool_scale is None:
-        sharded = shard_map(fn, mesh=mesh, in_specs=(kv, kv, P(), P()),
-                            out_specs=kv, check_rep=False)
+        sharded = jax.shard_map(fn, mesh=mesh, in_specs=(kv, kv, P(), P()),
+                                out_specs=kv, check_vma=False)
         return sharded(pool, new, pages, pos)
     sc = _head_spec(pool_scale, -1) if split else P()
-    sharded = shard_map(fn, mesh=mesh, in_specs=(kv, kv, P(), P(), sc),
-                        out_specs=(kv, sc), check_rep=False)
+    sharded = jax.shard_map(fn, mesh=mesh, in_specs=(kv, kv, P(), P(), sc),
+                            out_specs=(kv, sc), check_vma=False)
     return sharded(pool, new, pages, pos, pool_scale)
 
 
@@ -270,8 +271,8 @@ def attention_decode_paged(q, k_pool, v_pool, pages, lengths, *,
 
     XLA modes lower to the gather-then-dense reference — one extra full
     HBM pass plus a transient dense copy sized by the worst-case table
-    width. Pallas modes walk the page table in VMEM (double-buffered block
-    DMAs, no materialized gather): :mod:`.flash_attention.paged_attention`.
+    width. Pallas modes walk the page table in VMEM (one pipelined DMA per
+    block, no materialized gather): :mod:`.flash_attention.paged_attention`.
 
     Quantized pools (int8/fp8) pass their (NB, bs, Hkv) scale arrays via
     ``k_scale``/``v_scale``; see the matrix above for where each mode
@@ -370,30 +371,13 @@ def paged_cache_write(pool, new, pages, pos, *, pool_scale=None):
                                   interpret=(mode == "pallas_interpret"))
 
         return _tp_write_call(_call, pool, new, pages, pos)
-    nb, bs = pool.shape[0], pool.shape[1]
-    B, C = new.shape[0], new.shape[1]
-    MB = pages.shape[1]
-    p = pos[:, None] + jax.numpy.arange(C, dtype=pos.dtype)[None, :]
-    col = p // bs
-    blk = jax.numpy.take_along_axis(
-        pages, jax.numpy.clip(col, 0, MB - 1), axis=1)
-    blk = jax.numpy.where(col < MB, blk, 0)    # overrun -> garbage block
-    flat = (blk * bs + p % bs).reshape(-1)
+    slots = fa_ref.paged_slots(pages, pos, new.shape[1], pool.shape[1])
     if pool_scale is not None:
         from repro.kernels import quant
         new_q, s_new = quant.quantize(new, pool.dtype)
-        pool_flat = pool.reshape((nb * bs,) + pool.shape[2:])
-        pool_flat = pool_flat.at[flat].set(
-            new_q.reshape((B * C,) + new_q.shape[2:]))
-        scale_flat = pool_scale.reshape((nb * bs,) + pool_scale.shape[2:])
-        scale_flat = scale_flat.at[flat].set(
-            s_new.astype(pool_scale.dtype).reshape((B * C,) + s_new.shape[2:]))
-        return (pool_flat.reshape(pool.shape),
-                scale_flat.reshape(pool_scale.shape))
-    pool_flat = pool.reshape((nb * bs,) + pool.shape[2:])
-    pool_flat = pool_flat.at[flat].set(
-        new.astype(pool.dtype).reshape((B * C,) + new.shape[2:]))
-    return pool_flat.reshape(pool.shape)
+        return (fa_ref.scatter_slots(pool, slots, new_q),
+                fa_ref.scatter_slots(pool_scale, slots, s_new))
+    return fa_ref.scatter_slots(pool, slots, new)
 
 
 def ssd(x, dt, A, Bm, Cm, D=None, *, chunk: int = 64, h0=None,

@@ -1,8 +1,8 @@
 """Quantized KV block pools: dtypes, scales, and the jnp quant/dequant.
 
-The paged KV pools can be stored in int8 (or fp8 where the platform
-dtype exists) instead of the compute dtype, halving (vs bf16) the HBM
-bytes every block costs — the block budget every other subsystem
+The paged KV pools can be stored in int8 or fp8 (e4m3fn) instead of the
+compute dtype, halving (vs bf16) the HBM bytes every block costs — the
+block budget every other subsystem
 (tiering, spec decode, tp sharding) spends doubles for free. This module
 owns the *scheme*; the kernels (:mod:`.flash_attention.paged_attention`)
 and the XLA references (:mod:`.flash_attention.ref`) own the fusion.
@@ -37,12 +37,10 @@ import jax.numpy as jnp
 
 SCALE_DTYPE = jnp.float32
 
-# fp8 support depends on the jax build; gate rather than require
-FP8_DTYPE = getattr(jnp, "float8_e4m3fn", None)
+FP8_DTYPE = jnp.float8_e4m3fn
 
-_QMAX = {jnp.dtype(jnp.int8): 127.0}
-if FP8_DTYPE is not None:
-    _QMAX[jnp.dtype(FP8_DTYPE)] = 448.0   # e4m3fn max finite
+_QMAX = {jnp.dtype(jnp.int8): 127.0,
+         jnp.dtype(FP8_DTYPE): 448.0}     # e4m3fn max finite
 
 # floor on the absmax so a silent/zero token quantizes to zeros with a
 # harmless scale instead of dividing by zero
@@ -62,24 +60,17 @@ def qmax(dtype: Any) -> float:
 def resolve_kv_dtype(name: str | None, compute_dtype: Any):
     """Map a ``--kv-dtype`` string to a concrete storage dtype.
 
-    ``None``/"native" keep the compute dtype (unquantized). "fp8" falls
-    back to int8 with a warning when the jax build has no float8.
+    ``None``/"native" keep the compute dtype (unquantized).
     """
     if name is None or name in ("", "native"):
         return jnp.dtype(compute_dtype)
     table = {
         "int8": jnp.int8,
+        "fp8": FP8_DTYPE,
         "bf16": jnp.bfloat16, "bfloat16": jnp.bfloat16,
         "fp16": jnp.float16, "float16": jnp.float16, "half": jnp.float16,
         "fp32": jnp.float32, "float32": jnp.float32,
     }
-    if name == "fp8":
-        if FP8_DTYPE is None:
-            import warnings
-            warnings.warn("this jax build has no float8_e4m3fn; "
-                          "kv_dtype=fp8 falls back to int8", RuntimeWarning)
-            return jnp.dtype(jnp.int8)
-        return jnp.dtype(FP8_DTYPE)
     if name not in table:
         raise ValueError(f"unknown kv dtype {name!r} (expected int8, fp8, "
                          f"bf16, fp16, fp32 or native)")
@@ -91,7 +82,7 @@ def kv_dtype_name(dtype: Any) -> str:
     d = jnp.dtype(dtype)
     if d == jnp.dtype(jnp.int8):
         return "int8"
-    if FP8_DTYPE is not None and d == jnp.dtype(FP8_DTYPE):
+    if d == jnp.dtype(FP8_DTYPE):
         return "fp8"
     return {"bfloat16": "bf16", "float16": "fp16",
             "float32": "fp32"}.get(d.name, d.name)

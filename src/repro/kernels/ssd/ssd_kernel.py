@@ -7,10 +7,17 @@ grid steps instead of across GPU thread blocks. Per chunk the kernel does the
 three MXU matmuls of the duality form (C·Bᵀ masked by the decay matrix,
 state read-out, state update) in fp32.
 
-Chunk tiles: Q x N and Q x P with Q, N, P multiples of the 128-lane /
-8-sublane layout where the config allows (Q=128+ recommended).
+Layout: the wrapper lays every operand out head-major (``(B, H, S, P)``,
+``(B, G, S, N)``) so each block is a ``(Q, P)`` / ``(Q, N)`` tile of one
+head — a one-head slice of a ``(B, S, H, P)`` array is a size-1 sublane
+block, which the TPU compiler rejects. The elementwise prologue
+(``x * dt``, ``dt * A``) and the ``D`` skip term run in XLA around the
+kernel. The per-chunk log-decay arrives as a ``(1, Q)`` row; Mosaic has
+no cumsum, so the kernel builds the cumulative decay as masked lane and
+sublane sums.
 
-Validated against ``ref.ssd_chunked``/``ref.ssd_naive`` in interpret mode.
+Validated against ``ref.ssd_chunked``/``ref.ssd_naive`` in interpret mode
+and compiled for TPU v5e (``tests/test_tpu_compile.py``).
 """
 
 from __future__ import annotations
@@ -23,8 +30,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, hout_ref,
-                h_scr, *, chunk: int, has_d: bool):
+def _ssd_kernel(x_ref, ld_ref, b_ref, c_ref, y_ref, hout_ref, h_scr, *,
+                chunk: int):
     ci = pl.program_id(2)
     nc = pl.num_programs(2)
 
@@ -32,50 +39,44 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, hout_ref,
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    x = x_ref[0, 0, :, 0, :].astype(jnp.float32)       # (Q, P)
-    dt = dt_ref[0, 0, :, 0].astype(jnp.float32)        # (Q,)
-    A = a_ref[0]                                       # scalar (f32, SMEM)
-    Bm = b_ref[0, 0, :, 0, :].astype(jnp.float32)      # (Q, N)
-    Cm = c_ref[0, 0, :, 0, :].astype(jnp.float32)      # (Q, N)
+    xbar = x_ref[0, 0].astype(jnp.float32)             # (Q, P)  x * dt
+    Bm = b_ref[0, 0].astype(jnp.float32)               # (Q, N)
+    Cm = c_ref[0, 0].astype(jnp.float32)               # (Q, N)
+    logd = ld_ref[0, 0, 0]                             # (1, Q)  dt * A
 
-    logd = dt * A                                      # (Q,)
-    csum = jnp.cumsum(logd)                            # (Q,)
-    xbar = x * dt[:, None]                             # (Q, P)
-
-    # intra-chunk: masked (C Bᵀ) with pairwise decay
     i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     j = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    ldiff = csum[:, None] - csum[None, :]              # log decay i<-j
-    L = jnp.where(i >= j, jnp.exp(ldiff), 0.0)         # (Q, Q)
+    # cumulative log decay as a column (csum_c[i] = sum_{j<=i} logd[j])
+    # and as a row; the row needs logd as a column first (diagonal pick)
+    csum_c = jnp.sum(jnp.where(j <= i, logd, 0.0), axis=1, keepdims=True)
+    logd_c = jnp.sum(jnp.where(j == i, logd, 0.0), axis=1, keepdims=True)
+    csum_r = jnp.sum(jnp.where(i <= j, logd_c, 0.0), axis=0, keepdims=True)
+
+    # intra-chunk: masked (C Bᵀ) with pairwise decay i <- j
+    L = jnp.where(i >= j, jnp.exp(csum_c - csum_r), 0.0)   # (Q, Q)
     scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
     y = jax.lax.dot_general(scores * L, xbar, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (Q, P)
 
     # inter-chunk: read out the carried state
-    decay_from_start = jnp.exp(csum)[:, None]          # (Q, 1)
     h_prev = h_scr[...]                                # (N, P)
-    y += jax.lax.dot_general(Cm * decay_from_start, h_prev,
+    y += jax.lax.dot_general(Cm * jnp.exp(csum_c), h_prev,
                              (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)
-
-    if has_d:
-        y += x * d_ref[0]
-
-    y_ref[0, 0, :, 0, :] = y.astype(y_ref.dtype)
+    y_ref[0, 0] = y
 
     # state update: h = exp(sum logd) h + (B * decay_to_end)ᵀ xbar
-    total = csum[chunk - 1]
-    decay_to_end = jnp.exp(total - csum)[:, None]      # (Q, 1)
+    total = csum_c[chunk - 1:, :]                      # (1, 1)
     h_new = jnp.exp(total) * h_prev \
-        + jax.lax.dot_general(Bm * decay_to_end, xbar,
+        + jax.lax.dot_general(Bm * jnp.exp(total - csum_c), xbar,
                               (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
     h_scr[...] = h_new
 
     @pl.when(ci == nc - 1)
     def _emit():
-        hout_ref[0, 0, :, :] = h_new
+        hout_ref[0, 0] = h_new
 
 
 def ssd(x, dt, A, Bm, Cm, D=None, *, chunk: int = 64, h0=None,
@@ -95,46 +96,44 @@ def ssd(x, dt, A, Bm, Cm, D=None, *, chunk: int = 64, h0=None,
     nc = S // chunk
     group = H // G
 
-    grid = (B, H, nc)
-    kernel = functools.partial(_ssd_kernel, chunk=chunk, has_d=D is not None)
-    d_arr = (D if D is not None else jnp.zeros((H,))).astype(jnp.float32)
+    dt = dt.astype(jnp.float32)
+    # head-major operands: (B, H, S, P), (B, H, nc, 1, Q), (B, G, S, N)
+    xbar = (x.astype(jnp.float32) * dt[..., None]).transpose(0, 2, 1, 3)
+    logd = (dt * A.astype(jnp.float32)).transpose(0, 2, 1) \
+        .reshape(B, H, nc, 1, chunk)
+    Bh, Ch = (m.transpose(0, 2, 1, 3) for m in (Bm, Cm))
 
+    x_spec = pl.BlockSpec((1, 1, chunk, P), lambda b, h, ci: (b, h, ci, 0))
+    bc_spec = pl.BlockSpec((1, 1, chunk, N),
+                           lambda b, h, ci, g=group: (b, h // g, ci, 0))
     y, hT = pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_ssd_kernel, chunk=chunk),
+        grid=(B, H, nc),
         in_specs=[
-            pl.BlockSpec((1, 1, chunk, 1, P),
-                         lambda b, h, ci: (b, ci, 0, h, 0)),
-            pl.BlockSpec((1, 1, chunk, 1),
-                         lambda b, h, ci: (b, ci, 0, h)),
-            pl.BlockSpec((1,), lambda b, h, ci: (h,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, chunk, 1, N),
-                         lambda b, h, ci, g=group: (b, ci, 0, h // g, 0)),
-            pl.BlockSpec((1, 1, chunk, 1, N),
-                         lambda b, h, ci, g=group: (b, ci, 0, h // g, 0)),
-            pl.BlockSpec((1,), lambda b, h, ci: (h,),
-                         memory_space=pltpu.SMEM),
+            x_spec,
+            pl.BlockSpec((1, 1, 1, 1, chunk),
+                         lambda b, h, ci: (b, h, ci, 0, 0)),
+            bc_spec,
+            bc_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, chunk, 1, P),
-                         lambda b, h, ci: (b, ci, 0, h, 0)),
+            x_spec,
             pl.BlockSpec((1, 1, N, P), lambda b, h, ci: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, nc, chunk, H, P), x.dtype),
+            jax.ShapeDtypeStruct((B, H, S, P), jnp.float32),
             jax.ShapeDtypeStruct((B, H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x.reshape(B, nc, chunk, H, P),
-      dt.reshape(B, nc, chunk, H),
-      A.astype(jnp.float32),
-      Bm.reshape(B, nc, chunk, G, N),
-      Cm.reshape(B, nc, chunk, G, N),
-      d_arr)
+    )(xbar, logd, Bh, Ch)
 
-    y = y.reshape(B, S, H, P)
+    y = y.transpose(0, 2, 1, 3)
+    if D is not None:
+        y = y + x.astype(jnp.float32) * D.astype(jnp.float32)[:, None]
+    y = y.astype(x.dtype)
     if return_state:
         return y, jnp.moveaxis(hT, 2, 3)  # (B, H, P, N)
     return y

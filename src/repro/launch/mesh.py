@@ -17,6 +17,10 @@ Serving meshes use the same two ICI axes with serving semantics
   runs with ``data = 1`` (continuous batching fills the batch axis, there
   is nothing to split).
 
+Every mesh is built with ``Auto`` axis types: the models place
+activations with ``with_sharding_constraint`` and leave the rest to the
+partitioner, which jax's default ``Explicit`` axes reject.
+
 Functions, not module constants — importing this module never touches jax
 device state (smoke tests must keep seeing 1 device).
 """
@@ -26,6 +30,12 @@ from __future__ import annotations
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...], devices):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -38,7 +48,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {n} devices, have {len(devices)} — the "
             "dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count"
             " before importing jax")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
 
 
 def make_host_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
@@ -51,7 +61,7 @@ def make_host_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
             "XLA_FLAGS=--xla_force_host_platform_device_count before "
             "importing jax (a silent [:n] slice would build a mesh of the "
             "wrong size)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
 
 
 def make_serving_mesh(tp: int, *, data: int = 1):
@@ -93,7 +103,6 @@ def make_replica_meshes(replicas: int, tp: int = 1):
             "--xla_force_host_platform_device_count before importing jax, "
             "or lower --replicas/--tp")
     return [
-        jax.make_mesh((1, tp), ("data", "model"),
-                      devices=devices[r * tp:(r + 1) * tp])
+        _auto_mesh((1, tp), ("data", "model"), devices[r * tp:(r + 1) * tp])
         for r in range(replicas)
     ]

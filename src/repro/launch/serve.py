@@ -42,19 +42,20 @@ Flags:
                    batch run and warm-load them (into the host tier) at
                    startup — digest-keyed, CRC'd, layout-checked; a stale
                    or corrupt store logs a warning and serves cold
-  --kernels MODE   kernel mode for the jitted step: xla (default; gather-
-                   then-dense paged references), xla_chunked, pallas (Pallas
-                   paged-attention page-table walk — real TPUs only), or
-                   pallas_interpret (same kernels on the CPU interpreter).
-                   Defaults to $REPRO_KERNELS when set.
+  --kernels MODE   kernel mode for the jitted step; by default the platform
+                   picks it: the compiled Pallas kernels on a TPU (the
+                   paged-attention page-table walk), the ambient context
+                   elsewhere (the gather-then-dense XLA references). Set it
+                   to compare: xla, xla_chunked, pallas (TPU only; raises
+                   elsewhere) or pallas_interpret (the same kernels on the
+                   CPU interpreter). Defaults to $REPRO_KERNELS when set.
   --kv-dtype D     paged KV pool storage dtype: native (default; the
                    compute dtype), int8 or fp8 (quantized pools with
                    per-(token, kv-head) scales — quant fused into the
                    write scatter, dequant into the attention walk; ~0.53x
                    the bf16 HBM bytes/token at head_dim 64, so the same
                    pool holds ~2x the cached tokens), or bf16/fp16/fp32.
-                   Defaults to $REPRO_KV_DTYPE when set. fp8 falls back
-                   to int8 with a warning on jax builds without float8.
+                   Defaults to $REPRO_KV_DTYPE when set.
   --tp N           tensor parallelism: shard params and the paged KV pools
                    over an N-wide (data=1, model=N) mesh so one engine
                    spans N devices (each holds 1/N of the KV bytes). Needs
@@ -193,8 +194,9 @@ def main(argv=None) -> int:
     ap.add_argument("--kernels",
                     default=os.environ.get("REPRO_KERNELS") or None,
                     choices=kernel_modes,
-                    help="kernel mode for the serving step "
-                         "(default: $REPRO_KERNELS or ambient context)")
+                    help="kernel mode for the serving step (default: "
+                         "$REPRO_KERNELS, else pallas on a TPU and the "
+                         "ambient context elsewhere)")
     kv_dtypes = ["native", "int8", "fp8", "bf16", "fp16", "fp32"]
     ap.add_argument("--kv-dtype",
                     default=os.environ.get("REPRO_KV_DTYPE") or None,
@@ -225,8 +227,11 @@ def main(argv=None) -> int:
 
     import repro.core as nn
     from repro.configs import get_arch
+    from repro.launch.cache import enable_compile_cache
     from repro.models.registry import get_model
     from repro.serving.engine import Request, ServingEngine
+
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.smoke:
@@ -301,7 +306,9 @@ def main(argv=None) -> int:
               f"{engine.block_size} tok, {engine.kv_dtype} pools "
               f"({engine.kv_bytes_per_token():.0f} B/tok)"
               f"{', prefix cache on' if engine.prefix else ''}"
-              f" | kernels={args.kernels or 'ambient'}", flush=True)
+              f" | kernels="
+              f"{engine.kernels or nn.get_default_context().kernels}",
+              flush=True)
         if engine.prefix is not None and hasattr(engine.prefix, "host"):
             print(f"tiered KV: host pool {engine.prefix.host.capacity} "
                   f"blocks"

@@ -28,11 +28,12 @@ out of slots without recompilation. Since PR 2 the KV cache is **paged**:
 * recurrent state (SSM ``h``, conv windows) stays dense per slot — it is
   O(1) in sequence — and is zeroed on slot reuse as before; families with
   no paged support at all (pure SSM, audio) fall back to the dense layout.
-* since PR 3 the attention hot path is kernel-mode selectable
-  (``kernels="pallas"`` on real TPUs walks the page table in VMEM with
-  double-buffered block DMAs instead of the gather-then-dense XLA
-  reference; ``"pallas_interpret"`` validates the same kernels on CPU).
-  The override scopes only the engine's jitted step, not the process.
+* the attention hot path follows the platform: on a TPU the engine runs
+  the compiled Pallas kernels (the paged walk pipelines each page-table
+  block into VMEM instead of the gather-then-dense XLA reference);
+  elsewhere the ambient context decides, and ``"pallas_interpret"``
+  validates the same kernels on CPU. The mode scopes only the engine's
+  jitted step, not the process.
 * since PR 4 one engine can span a **(data, model) mesh**: ``tp=N`` (or an
   explicit ``mesh=``) shards the params Megatron-style and the paged K/V
   pools on the kv-head axis (:mod:`repro.launch.serve_shardings` owns the
@@ -84,14 +85,13 @@ gates this on positional pure-KV state). Default ``spec_k=0`` — the
 engine is byte-for-byte the PR-5 engine unless asked.
 
 Quantized KV pools (PR 10, ``kv_dtype="int8"``/``"fp8"``): the paged
-pools store int8 (or fp8 where the platform dtype exists) with
-per-(token-slot, kv-head) float32 scale leaves riding alongside
-(``"k_scale"``/``"v_scale"``). Quant fuses into the write scatter,
-dequant into the attention walk (in VMEM on the Pallas path) — no
-dequantized pool ever materializes in HBM, and every host-side
-subsystem (allocator, tiering spill/fetch, prefix store, tp sharding)
-carries the scale leaves automatically because they are ordinary KV
-leaves with the block axis at 1. Resolution order for the dtype:
+pools store int8 or fp8 (e4m3fn) with per-(token-slot, kv-head) float32
+scale leaves riding alongside (``"k_scale"``/``"v_scale"``). Quant fuses
+into the write scatter, dequant into the attention walk (in VMEM on the
+Pallas path) — no dequantized pool ever materializes in HBM, and every
+host-side subsystem (allocator, tiering spill/fetch, prefix store, tp
+sharding) carries the scale leaves automatically because they are
+ordinary KV leaves with the block axis at 1. Resolution order for the dtype:
 explicit ``kv_dtype=`` > ``$REPRO_KV_DTYPE`` > ``CacheSpec.kv_dtype``.
 ``kv_bytes_per_token()`` reports the realized per-token HBM cost
 (pool + scales); at D = 64, int8 is ~0.53x of bf16.
@@ -263,16 +263,12 @@ class ServingEngine:
                     params, _sh.params_shardings(params))
         else:
             self._env = None
-        # kernel-mode override for the jitted step (None = ambient context):
-        # "pallas" runs the paged-attention page-table walk on real TPUs,
-        # "pallas_interpret" the same kernel logic on CPU, "xla*" the
-        # gather-then-dense references. Reject typos here, at the boundary —
-        # an unknown string would otherwise dispatch to compiled Pallas and
-        # die deep inside Mosaic lowering.
-        if kernels and kernels not in _ctx.KERNEL_MODES:
-            raise ValueError(f"unknown kernels mode {kernels!r}; "
-                             f"one of {_ctx.KERNEL_MODES}")
-        self.kernels = kernels
+        # kernel mode pinned for the jitted step, resolved from the
+        # platform when not given: compiled Pallas on a TPU, the ambient
+        # context (None) elsewhere. "pallas_interpret" runs the same kernel
+        # logic on CPU, "xla*" the gather-then-dense references. Typos and
+        # "pallas" off a TPU raise here, not deep inside Mosaic lowering.
+        self.kernels = _ctx.resolve_kernels(kernels)
         self.B = max_batch
         self.max_seq = max_seq
         # APIs without a prefill entry fall back to one-token absorption

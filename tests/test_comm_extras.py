@@ -26,18 +26,18 @@ def test_single_giant_tensor_gets_own_bucket():
 
 EF_CODE = """
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_host_mesh
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.comm import error_feedback_reduce
 
-mesh = jax.make_mesh((4,), ("data",))
+mesh = make_host_mesh((4,), ("data",))
 rng = np.random.default_rng(0)
 x = jnp.asarray(rng.normal(size=(4, 256)), jnp.float32)
 err0 = jnp.zeros((4, 256), jnp.float32)
 
-f = shard_map(lambda v, e: error_feedback_reduce(v, e, "data"),
-              mesh=mesh, in_specs=(P("data"), P("data")),
-              out_specs=(P("data"), P("data")), check_rep=False)
+f = jax.shard_map(lambda v, e: error_feedback_reduce(v, e, "data"),
+                  mesh=mesh, in_specs=(P("data"), P("data")),
+                  out_specs=(P("data"), P("data")), check_vma=False)
 exact = np.asarray(x).mean(0)
 
 # the EF guarantee: the RUNNING MEAN of estimates converges to the exact

@@ -10,19 +10,19 @@ import numpy as np
 import pytest
 
 from repro.distributed.sharding import ShardingEnv, param_spec, sharding_env, spec_for
+from repro.launch.mesh import make_host_mesh
 
 
 def test_sharding_rules_degrade_on_indivisible():
-    import jax
     from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_host_mesh((1,), ("model",))
     env = ShardingEnv(mesh=mesh, axis_rules={"heads": "model"},
                       param_rules=[(r"w$", ("heads", None))])
     with sharding_env(env):
         # 8 heads % 1 == 0 -> sharded; 7 % 2 would degrade (simulated below)
         assert param_spec("layer/w", (8, 4)) == P("model", None)
 
-    mesh2 = jax.make_mesh((1,), ("model",))
+    mesh2 = make_host_mesh((1,), ("model",))
     env2 = ShardingEnv(mesh=mesh2, axis_rules={"heads": "model"})
     with sharding_env(env2):
         assert spec_for(("heads",), (8,)) == P("model")
@@ -33,7 +33,6 @@ def test_make_host_mesh_rejects_insufficient_devices():
     make_production_mesh instead of silently slicing jax.devices()[:n]
     into a wrong-sized mesh."""
     import jax
-    from repro.launch.mesh import make_host_mesh
     have = len(jax.devices())
     with pytest.raises(RuntimeError,
                        match=f"needs {8 * have} devices, have {have}"):
@@ -55,10 +54,27 @@ def test_make_serving_mesh_shape():
         make_serving_mesh(8 * len(jax.devices()))
 
 
-def test_duplicate_mesh_axis_dropped():
+def test_repo_meshes_use_auto_axes():
+    """jax.make_mesh defaults to Explicit axes, under which the models'
+    with_sharding_constraint raises; every mesh the repo builds is Auto."""
     import jax
+    import jax.numpy as jnp
+    from jax.sharding import AxisType
+    from repro.distributed.sharding import constrain
+    from repro.launch.mesh import make_replica_meshes, make_serving_mesh
+    meshes = [make_serving_mesh(1), *make_replica_meshes(1),
+              make_host_mesh((1,), ("data",))]
+    for mesh in meshes:
+        assert set(mesh.axis_types) == {AxisType.Auto}
+    env = ShardingEnv(mesh=meshes[0], axis_rules={"heads": "model"})
+    with sharding_env(env):
+        y = jax.jit(lambda x: constrain(x, "heads") * 2)(jnp.ones(4))
+    assert float(y.sum()) == 8.0
+
+
+def test_duplicate_mesh_axis_dropped():
     from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_host_mesh((1,), ("model",))
     env = ShardingEnv(mesh=mesh, axis_rules={"a": "model", "b": "model"})
     with sharding_env(env):
         # both dims want 'model'; second use must degrade to None
@@ -66,9 +82,8 @@ def test_duplicate_mesh_axis_dropped():
 
 
 def test_stacked_param_rule_padding():
-    import jax
     from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_host_mesh((1,), ("model",))
     env = ShardingEnv(mesh=mesh,
                       axis_rules={"mlp": "model", "layers": None},
                       param_rules=[(r"kernel$", ("embed", "mlp"))])
@@ -80,13 +95,14 @@ def test_stacked_param_rule_padding():
 
 DP_GRADS_CODE = """
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_host_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 import repro.core as nn
 import repro.core.parametric as PF
 import repro.core.functions as F
 
 assert len(jax.devices()) == 8, jax.devices()
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_host_mesh((8,), ("data",))
 
 def model(tokens, labels):
     h = PF.embed(tokens, 64, 16, name="emb")
@@ -123,27 +139,27 @@ def test_dp_grads_match_single_device(subproc):
 
 COMM_CODE = """
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_host_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.comm import Communicator, compressed_all_reduce
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_host_mesh((8,), ("data",))
 comm = Communicator(mesh, axis="data")
 x = jnp.arange(32, dtype=jnp.float32).reshape(8, 4)
 
 # all_reduce inside shard_map == global sum
-f = shard_map(lambda v: comm.all_reduce(v), mesh=mesh,
-              in_specs=P("data"), out_specs=P("data"), check_rep=False)
+f = jax.shard_map(lambda v: comm.all_reduce(v), mesh=mesh,
+                  in_specs=P("data"), out_specs=P("data"), check_vma=False)
 y = f(x)
 want = np.tile(np.asarray(x).sum(0, keepdims=True), (8, 1))
 np.testing.assert_allclose(np.asarray(y), want, rtol=1e-6)
 
 # reduce_scatter + all_gather == all_reduce (scatter over the wide axis)
 x2 = jnp.arange(8 * 32, dtype=jnp.float32).reshape(8, 32)
-g = shard_map(lambda v: comm.all_gather(comm.reduce_scatter(v, axis=1),
-                                        axis=1),
-              mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-              check_rep=False)
+g = jax.shard_map(lambda v: comm.all_gather(comm.reduce_scatter(v, axis=1),
+                                            axis=1),
+                  mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+                  check_vma=False)
 want2 = np.tile(np.asarray(x2).sum(0, keepdims=True), (8, 1))
 np.testing.assert_allclose(np.asarray(g(x2)), want2, rtol=1e-6)
 
@@ -152,9 +168,9 @@ rng = np.random.default_rng(0)
 v = jnp.asarray(rng.normal(size=(8, 64)), jnp.float32)
 exact = np.asarray(v).mean(0)
 for method, tol in (("bf16", 2e-2), ("int8", 3e-2)):
-    h = shard_map(lambda z: compressed_all_reduce(z, "data", method=method),
-                  mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-                  check_rep=False)
+    h = jax.shard_map(lambda z: compressed_all_reduce(z, "data", method=method),
+                      mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+                      check_vma=False)
     got = np.asarray(h(v))[0]
     scale = np.abs(exact).max() + 1e-9
     assert np.abs(got - exact).max() / scale < tol, (method, np.abs(got-exact).max())
@@ -169,10 +185,11 @@ def test_communicator_collectives(subproc):
 
 PIPELINE_CODE = """
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_host_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.distributed.pipeline import make_pipeline_fn
 
-mesh = jax.make_mesh((4,), ("pod",))
+mesh = make_host_mesh((4,), ("pod",))
 S, M, MB, D = 4, 8, 2, 16
 rng = np.random.default_rng(0)
 Ws = jnp.asarray(rng.normal(size=(S, D, D)) * 0.3, jnp.float32)
@@ -211,18 +228,19 @@ def test_pipeline_parallel_matches_sequential(subproc):
 
 ELASTIC_CODE = """
 import jax, jax.numpy as jnp, numpy as np, tempfile
+from repro.launch.mesh import make_host_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint.manager import CheckpointManager
 
 # save while sharded over 8 devices; restore re-sharded over 4 (elastic)
-mesh8 = jax.make_mesh((8,), ("data",))
+mesh8 = make_host_mesh((8,), ("data",))
 x = jax.device_put(jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
                    NamedSharding(mesh8, P("data")))
 state = {"w": x}
 with tempfile.TemporaryDirectory() as d:
     mgr = CheckpointManager(d)
     mgr.save(1, state)
-    mesh4 = jax.make_mesh((4,), ("data",), devices=jax.devices()[:4])
+    mesh4 = make_host_mesh((4,), ("data",))
     sh4 = {"w": NamedSharding(mesh4, P("data"))}
     got = mgr.restore(1, {"w": np.zeros((8, 8), np.float32)}, shardings=sh4)
     assert got["w"].sharding.mesh.shape["data"] == 4
@@ -238,6 +256,7 @@ def test_elastic_reshard_restore(subproc):
 
 MOE_EP_CODE = """
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_host_mesh
 import dataclasses
 from jax.sharding import NamedSharding, PartitionSpec as P
 import repro.core as nn
@@ -253,7 +272,7 @@ toks = jnp.asarray(rng.integers(1, cfg.vocab_size, (4, 32)), jnp.int32)
 params = nn.init(lambda t: api.forward(t), jax.random.key(0), toks)
 ref, _ = nn.apply(lambda t: api.forward(t), params, toks)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh((2, 4), ("data", "model"))
 env = ShardingEnv(mesh=mesh,
                   axis_rules={"batch": "data", "expert": "model",
                               "expert_group": "data"},

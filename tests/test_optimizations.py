@@ -82,6 +82,7 @@ class TestChunkedLoss:
 
 MERGED_CODE = """
 import jax, jax.numpy as jnp, numpy as np
+from repro.launch.mesh import make_host_mesh
 import repro.core as nn
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import ModelConfig
@@ -97,7 +98,7 @@ toks = jnp.asarray(rng.integers(1, 64, (4, 16)), jnp.int32)
 params = nn.init(lambda t: T.forward(cfg, t), jax.random.key(0), toks)
 ref, _ = nn.apply(lambda t: T.forward(cfg, t), params, toks)  # no mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh((2, 4), ("data", "model"))
 env = ShardingEnv(mesh=mesh,
                   axis_rules={"batch": "data", "heads": "model",
                               "batch_kv": ("data", "model"),

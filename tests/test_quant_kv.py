@@ -15,7 +15,7 @@ write scatter, dequant into the attention walk. Covered here:
 * engine end-to-end: identical int8 streams across kernel modes, greedy
   stability vs unquantized pools (divergence rate bounded + reported),
   spec-decode bitwise guarantee, bytes/token accounting (<= 0.55x bf16
-  at D=64), $REPRO_KV_DTYPE resolution, fp8 fallback.
+  at D=64), $REPRO_KV_DTYPE resolution, fp8 pools.
 """
 
 import dataclasses
@@ -34,14 +34,12 @@ from repro.kernels.flash_attention import ref as fa_ref
 from repro.models.registry import get_model
 from repro.serving.engine import Request, ServingEngine
 
-QDTYPES = [jnp.int8] + ([quant.FP8_DTYPE] if quant.FP8_DTYPE else [])
-QIDS = ["int8"] + (["fp8"] if quant.FP8_DTYPE else [])
+QDTYPES = [jnp.int8, quant.FP8_DTYPE]
+QIDS = ["int8", "fp8"]
 # attention-output tolerance vs the fp32 oracle: int8 keeps ~0.4%
 # relative error per element, fp8 e4m3 (3 mantissa bits) several times
 # that — the ladder the acceptance criteria ask for
-TOL = {jnp.dtype(jnp.int8): 5e-2}
-if quant.FP8_DTYPE:
-    TOL[jnp.dtype(quant.FP8_DTYPE)] = 1.5e-1
+TOL = {jnp.dtype(jnp.int8): 5e-2, jnp.dtype(quant.FP8_DTYPE): 1.5e-1}
 
 
 def rand(shape, seed, dtype=jnp.float32):
@@ -96,12 +94,8 @@ def test_resolve_kv_dtype_names():
     assert not quant.is_quantized(jnp.bfloat16)
     with pytest.raises(ValueError):
         quant.resolve_kv_dtype("int7", jnp.float32)
-    if quant.FP8_DTYPE is None:
-        with pytest.warns(RuntimeWarning, match="falls back to int8"):
-            assert quant.resolve_kv_dtype("fp8", jnp.float32) == jnp.int8
-    else:
-        got = quant.resolve_kv_dtype("fp8", jnp.float32)
-        assert quant.is_quantized(got) and quant.kv_dtype_name(got) == "fp8"
+    got = quant.resolve_kv_dtype("fp8", jnp.float32)
+    assert quant.is_quantized(got) and quant.kv_dtype_name(got) == "fp8"
 
 
 # ---------------------------------------------------------------------- #
@@ -365,14 +359,8 @@ def test_engine_env_var_resolution(monkeypatch):
 
 
 def test_engine_fp8_requested_always_quantizes():
-    """kv_dtype=fp8 quantizes on every build: natively where float8
-    exists, else falling back to int8 with a warning — never silently
+    """kv_dtype=fp8 allocates float8_e4m3fn pools — never silently
     unquantized."""
-    if quant.FP8_DTYPE is None:
-        with pytest.warns(RuntimeWarning, match="falls back to int8"):
-            _, eng = run_streams(CFG, n=2, kv_dtype="fp8")
-        assert eng.kv_dtype == "int8"
-    else:
-        _, eng = run_streams(CFG, n=2, kv_dtype="fp8")
-        assert eng.kv_dtype == "fp8"
-        assert quant.is_quantized(eng.state["k"].dtype)
+    _, eng = run_streams(CFG, n=2, kv_dtype="fp8")
+    assert eng.kv_dtype == "fp8"
+    assert eng.state["k"].dtype == quant.FP8_DTYPE
