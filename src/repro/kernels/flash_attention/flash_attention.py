@@ -156,6 +156,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
     return out.transpose(0, 2, 1, 3)[:, :Sq]
 
@@ -227,5 +228,6 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_decode",
     )(jnp.asarray(lengths, jnp.int32), qh, k_cache, v_cache)
     return out.reshape(B, 1, Hq, D)

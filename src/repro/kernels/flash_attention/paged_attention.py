@@ -184,6 +184,7 @@ def _paged_walk(q, k_pool, v_pool, pages, pos, *, scale, interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_attention",
     )(*operands)
     return (out.reshape(B, Hkv, C, rep, D).transpose(0, 2, 1, 3, 4)
             .reshape(B, C, Hq, D))
@@ -287,6 +288,7 @@ def paged_write(pool, new, pages, pos, *, pool_scale=None,
         # new, pool) -> pool aliases the single output
         input_output_aliases={3: 0},
         interpret=interpret,
+        name="paged_write",
     )(pages, pos, new, pool)
     if pool_scale is None:
         return pool

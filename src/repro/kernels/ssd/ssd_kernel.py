@@ -128,6 +128,7 @@ def ssd(x, dt, A, Bm, Cm, D=None, *, chunk: int = 64, h0=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ssd",
     )(xbar, logd, Bh, Ch)
 
     y = y.transpose(0, 2, 1, 3)

@@ -112,23 +112,24 @@ def attention(cfg: ModelConfig, x, cos, sin, *, name: str = "attn",
     B, S, d = x.shape
     H, Kh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
 
-    q = PF.dense(x, H * hd, name=f"{name}_q", use_bias=cfg.qkv_bias)
-    q = q.reshape(B, S, H, hd)
-    if cross_kv is None:
-        k = PF.dense(x, Kh * hd, name=f"{name}_k", use_bias=cfg.qkv_bias)
-        v = PF.dense(x, Kh * hd, name=f"{name}_v", use_bias=cfg.qkv_bias)
-        k = k.reshape(B, S, Kh, hd)
-        v = v.reshape(B, S, Kh, hd)
-        if use_rope:
-            q = F.apply_rope(q, cos, sin)
-            k = F.apply_rope(k, cos, sin)
-    else:
-        k, v = cross_kv
+    with jax.named_scope("attention/qkv"):
+        q = PF.dense(x, H * hd, name=f"{name}_q", use_bias=cfg.qkv_bias)
+        q = q.reshape(B, S, H, hd)
+        if cross_kv is None:
+            k = PF.dense(x, Kh * hd, name=f"{name}_k", use_bias=cfg.qkv_bias)
+            v = PF.dense(x, Kh * hd, name=f"{name}_v", use_bias=cfg.qkv_bias)
+            k = k.reshape(B, S, Kh, hd)
+            v = v.reshape(B, S, Kh, hd)
+            if use_rope:
+                q = F.apply_rope(q, cos, sin)
+                k = F.apply_rope(k, cos, sin)
+        else:
+            k, v = cross_kv
 
-    q = constrain(q, "batch", "seq", "heads", "head_dim")
-    if cross_kv is None:
-        k = constrain(k, "batch", "seq", "kv_heads", "head_dim")
-        v = constrain(v, "batch", "seq", "kv_heads", "head_dim")
+        q = constrain(q, "batch", "seq", "heads", "head_dim")
+        if cross_kv is None:
+            k = constrain(k, "batch", "seq", "kv_heads", "head_dim")
+            v = constrain(v, "batch", "seq", "kv_heads", "head_dim")
 
     # Merged batch×kv-head sharding: when the head count doesn't divide the
     # model axis (deepseek: 56 H / 8 KV on a 16-wide axis), flatten
@@ -154,13 +155,15 @@ def attention(cfg: ModelConfig, x, cos, sin, *, name: str = "attn",
             qm = constrain(qm, "batch_kv", "seq", None, None)
             km = constrain(km, "batch_kv", "seq", None, None)
             vm = constrain(vm, "batch_kv", "seq", None, None)
-            ym = K.attention(qm, km, vm, causal=causal, window=window,
-                             unroll=cfg.scan_unroll is True)
+            with jax.named_scope("attention/walk"):
+                ym = K.attention(qm, km, vm, causal=causal, window=window,
+                                 unroll=cfg.scan_unroll is True)
             ym = constrain(ym, "batch_kv", "seq", None, None)
             y = ym.reshape(B, Kh, S, rep, hd).transpose(0, 2, 1, 3, 4) \
                 .reshape(B, S, H * hd)
-            out = PF.dense(y, d, name=f"{name}_o",
-                           w_init=I.scaled_normal(1.0, H * hd))
+            with jax.named_scope("attention/out"):
+                out = PF.dense(y, d, name=f"{name}_o",
+                               w_init=I.scaled_normal(1.0, H * hd))
             return constrain(out, "batch", "seq", "embed"), None
 
     if cache is None and cross_kv is None:
@@ -178,11 +181,14 @@ def attention(cfg: ModelConfig, x, cos, sin, *, name: str = "attn",
             q = constrain(q, "batch", "attn_seq", None, None)
             k = constrain(k, "batch", None, None, None)
             v = constrain(v, "batch", None, None, None)
-            y = _fa_ref.mha_reference(q, k, v, causal=causal, window=window)
+            with jax.named_scope("attention/walk"):
+                y = _fa_ref.mha_reference(q, k, v, causal=causal,
+                                          window=window)
             y = constrain(y, "batch", "attn_seq", None, None)
             y = y.reshape(B, S, H * hd)
-            out = PF.dense(y, d, name=f"{name}_o",
-                           w_init=I.scaled_normal(1.0, H * hd))
+            with jax.named_scope("attention/out"):
+                out = PF.dense(y, d, name=f"{name}_o",
+                               w_init=I.scaled_normal(1.0, H * hd))
             return constrain(out, "batch", "seq", "embed"), None
 
     new_cache = None
@@ -200,77 +206,90 @@ def attention(cfg: ModelConfig, x, cos, sin, *, name: str = "attn",
             k_scale = v_scale = None
         pos_arr = jnp.asarray(cache_pos, jnp.int32)
         assert pos_arr.ndim == 1, "paged attention needs per-row positions"
-        if quantized:
-            k_pool, k_scale = K.paged_cache_write(k_pool, k, pages, pos_arr,
-                                                  pool_scale=k_scale)
-            v_pool, v_scale = K.paged_cache_write(v_pool, v, pages, pos_arr,
-                                                  pool_scale=v_scale)
-            k_scale = constrain(k_scale, None, None, "kv_heads")
-            v_scale = constrain(v_scale, None, None, "kv_heads")
-        else:
-            k_pool = K.paged_cache_write(k_pool, k, pages, pos_arr)
-            v_pool = K.paged_cache_write(v_pool, v, pages, pos_arr)
-        # pin the pool's kv-head sharding through the scatter so GSPMD
-        # carries it across layers (tp serving; no-op without a mesh)
-        k_pool = constrain(k_pool, None, None, "kv_heads", "head_dim")
-        v_pool = constrain(v_pool, None, None, "kv_heads", "head_dim")
-        if S > 1:
-            y = K.attention_prefill_paged(q, k_pool, v_pool, pages, pos_arr,
-                                          k_scale=k_scale, v_scale=v_scale)
-        else:
-            y = K.attention_decode_paged(q, k_pool, v_pool, pages,
-                                         pos_arr + 1,
-                                         k_scale=k_scale, v_scale=v_scale)
+        with jax.named_scope("attention/kv_write"):
+            if quantized:
+                k_pool, k_scale = K.paged_cache_write(
+                    k_pool, k, pages, pos_arr, pool_scale=k_scale)
+                v_pool, v_scale = K.paged_cache_write(
+                    v_pool, v, pages, pos_arr, pool_scale=v_scale)
+                k_scale = constrain(k_scale, None, None, "kv_heads")
+                v_scale = constrain(v_scale, None, None, "kv_heads")
+            else:
+                k_pool = K.paged_cache_write(k_pool, k, pages, pos_arr)
+                v_pool = K.paged_cache_write(v_pool, v, pages, pos_arr)
+            # pin the pool's kv-head sharding through the scatter so GSPMD
+            # carries it across layers (tp serving; no-op without a mesh)
+            k_pool = constrain(k_pool, None, None, "kv_heads", "head_dim")
+            v_pool = constrain(v_pool, None, None, "kv_heads", "head_dim")
+        with jax.named_scope("attention/walk"):
+            if S > 1:
+                y = K.attention_prefill_paged(
+                    q, k_pool, v_pool, pages, pos_arr,
+                    k_scale=k_scale, v_scale=v_scale)
+            else:
+                y = K.attention_decode_paged(
+                    q, k_pool, v_pool, pages, pos_arr + 1,
+                    k_scale=k_scale, v_scale=v_scale)
         new_cache = (k_pool, v_pool, k_scale, v_scale) if quantized \
             else (k_pool, v_pool)
     elif cache is not None:
         k_cache, v_cache = cache
         assert cache_pos is not None
         pos_arr = jnp.asarray(cache_pos, jnp.int32)
-        if pos_arr.ndim == 1:  # per-row positions (continuous batching)
-            upd = jax.vmap(
-                lambda c, n, p: lax.dynamic_update_slice(c, n, (p, 0, 0)))
-            k_cache = upd(k_cache, k.astype(k_cache.dtype), pos_arr)
-            v_cache = upd(v_cache, v.astype(v_cache.dtype), pos_arr)
-            row_pos = pos_arr
-        else:
-            k_cache = lax.dynamic_update_slice(
-                k_cache, k.astype(k_cache.dtype), (0, cache_pos, 0, 0))
-            v_cache = lax.dynamic_update_slice(
-                v_cache, v.astype(v_cache.dtype), (0, cache_pos, 0, 0))
-            row_pos = jnp.full((B,), pos_arr, jnp.int32)
-        k_cache = constrain(k_cache, "batch", "kv_seq", "kv_heads", "head_dim")
-        v_cache = constrain(v_cache, "batch", "kv_seq", "kv_heads", "head_dim")
-        if S > 1:
-            # chunked prefill: queries must stay causal *within* the chunk
-            # (query i sees cache[: pos + i + 1]), not all see pos + S.
-            y = K.attention_prefill(q, k_cache, v_cache, row_pos)
-        else:
-            y = K.attention_decode(q, k_cache, v_cache, row_pos + 1)
+        with jax.named_scope("attention/kv_write"):
+            if pos_arr.ndim == 1:  # per-row positions (continuous batching)
+                upd = jax.vmap(
+                    lambda c, n, p: lax.dynamic_update_slice(c, n, (p, 0, 0)))
+                k_cache = upd(k_cache, k.astype(k_cache.dtype), pos_arr)
+                v_cache = upd(v_cache, v.astype(v_cache.dtype), pos_arr)
+                row_pos = pos_arr
+            else:
+                k_cache = lax.dynamic_update_slice(
+                    k_cache, k.astype(k_cache.dtype), (0, cache_pos, 0, 0))
+                v_cache = lax.dynamic_update_slice(
+                    v_cache, v.astype(v_cache.dtype), (0, cache_pos, 0, 0))
+                row_pos = jnp.full((B,), pos_arr, jnp.int32)
+            k_cache = constrain(k_cache, "batch", "kv_seq", "kv_heads",
+                                "head_dim")
+            v_cache = constrain(v_cache, "batch", "kv_seq", "kv_heads",
+                                "head_dim")
+        with jax.named_scope("attention/walk"):
+            if S > 1:
+                # chunked prefill: queries must stay causal *within* the
+                # chunk (query i sees cache[: pos + i + 1]), not all see
+                # pos + S.
+                y = K.attention_prefill(q, k_cache, v_cache, row_pos)
+            else:
+                y = K.attention_decode(q, k_cache, v_cache, row_pos + 1)
         new_cache = (k_cache, v_cache)
     else:
-        y = K.attention(q, k, v, causal=causal and cross_kv is None,
-                        window=window, unroll=cfg.scan_unroll is True)
+        with jax.named_scope("attention/walk"):
+            y = K.attention(q, k, v, causal=causal and cross_kv is None,
+                            window=window, unroll=cfg.scan_unroll is True)
 
-    y = constrain(y, "batch", "seq", "heads", "head_dim")
-    y = y.reshape(B, S, H * hd)
-    out = PF.dense(y, d, name=f"{name}_o",
-                   w_init=I.scaled_normal(1.0, H * hd))
+    with jax.named_scope("attention/out"):
+        y = constrain(y, "batch", "seq", "heads", "head_dim")
+        y = y.reshape(B, S, H * hd)
+        out = PF.dense(y, d, name=f"{name}_o",
+                       w_init=I.scaled_normal(1.0, H * hd))
     return constrain(out, "batch", "seq", "embed"), new_cache
 
 
 def mlp(cfg: ModelConfig, x, *, name: str = "mlp", d_ff: int | None = None):
     d = x.shape[-1]
     dff = d_ff or cfg.d_ff
-    if cfg.act == "silu":  # gated (llama-style)
-        g = PF.dense(x, dff, name=f"{name}_gate")
-        u = PF.dense(x, dff, name=f"{name}_up")
-        h = F.silu(g) * u
-    else:
-        h = _activate(cfg, PF.dense(x, dff, name=f"{name}_up", use_bias=True))
-    h = constrain(h, "batch", "seq", "mlp")
-    out = PF.dense(h, d, name=f"{name}_down", w_init=I.scaled_normal(1.0, dff))
-    return constrain(out, "batch", "seq", "embed")
+    with jax.named_scope("mlp"):
+        if cfg.act == "silu":  # gated (llama-style)
+            g = PF.dense(x, dff, name=f"{name}_gate")
+            u = PF.dense(x, dff, name=f"{name}_up")
+            h = F.silu(g) * u
+        else:
+            h = _activate(cfg, PF.dense(x, dff, name=f"{name}_up",
+                                        use_bias=True))
+        h = constrain(h, "batch", "seq", "mlp")
+        out = PF.dense(h, d, name=f"{name}_down",
+                       w_init=I.scaled_normal(1.0, dff))
+        return constrain(out, "batch", "seq", "embed")
 
 
 # --------------------------------------------------------------------------- #
@@ -310,64 +329,72 @@ def moe_block(cfg: ModelConfig, x, *, name: str = "moe", token_mask=None):
     xg = x.reshape(nG, Gs, d)
     xg = constrain(xg, "expert_group", None, "embed")
 
-    # router in fp32 (numerics: paper's "BN in fp32" rule applies to routing)
-    router_w = nn.get_parameter_or_create(
-        f"{name}_router/kernel", (d, E), I.normal(0.02 / math.sqrt(d)),
-        dtype=jnp.float32)
-    logits = jnp.einsum("gsd,de->gse", xg.astype(jnp.float32), router_w)
-    probs = jax.nn.softmax(logits, axis=-1)                    # (nG,Gs,E)
-    gate_vals, expert_idx = lax.top_k(probs, k)                # (nG,Gs,k)
-    gate_vals = gate_vals / (jnp.sum(gate_vals, -1, keepdims=True) + 1e-9)
-
-    # position of each (token, choice) in its expert's queue
-    oh_flat = jax.nn.one_hot(expert_idx.reshape(nG, Gs * k), E,
-                             dtype=jnp.int32)                  # (nG,Gs*k,E)
-    if token_mask is not None:
-        # zeroed one-hots make pads rank -1 in every queue -> never kept
-        tm = jnp.repeat(token_mask.reshape(nG, Gs), k, axis=1)
-        oh_flat = oh_flat * tm[..., None].astype(jnp.int32)
-    pos_flat = jnp.cumsum(oh_flat, axis=1) * oh_flat - 1
-    pos_tok = pos_flat.max(-1).reshape(nG, Gs, k)              # (nG,Gs,k)
-    keep = (pos_tok >= 0) & (pos_tok < C)
+    with jax.named_scope("moe/router"):
+        # router in fp32 (numerics: paper's "BN in fp32" rule applies to
+        # routing)
+        router_w = nn.get_parameter_or_create(
+            f"{name}_router/kernel", (d, E), I.normal(0.02 / math.sqrt(d)),
+            dtype=jnp.float32)
+        logits = jnp.einsum("gsd,de->gse", xg.astype(jnp.float32), router_w)
+        probs = jax.nn.softmax(logits, axis=-1)                # (nG,Gs,E)
+        gate_vals, expert_idx = lax.top_k(probs, k)            # (nG,Gs,k)
+        gate_vals = gate_vals / (jnp.sum(gate_vals, -1, keepdims=True)
+                                 + 1e-9)
+        # Switch load-balance auxiliary loss
+        me = jnp.mean(probs, axis=(0, 1))                       # (E,)
+        ce = jnp.mean(jax.nn.one_hot(expert_idx[..., 0], E,
+                                     dtype=jnp.float32), axis=(0, 1))
+        aux = E * jnp.sum(me * ce)
 
     cdt = _ctx.get_default_context().policy.compute_dtype
-    dispatch = jnp.zeros((nG, Gs, E, C), cdt)
-    combine = jnp.zeros((nG, Gs, E, C), cdt)
-    for i in range(k):
-        ohe = jax.nn.one_hot(expert_idx[..., i], E, dtype=cdt)
-        ohc = jax.nn.one_hot(pos_tok[..., i], C, dtype=cdt)
-        sel = (ohe[..., None] * ohc[..., None, :]) \
-            * keep[..., i, None, None].astype(cdt)
-        dispatch = dispatch + sel
-        combine = combine + sel * gate_vals[..., i, None, None].astype(cdt)
-    dispatch = constrain(dispatch, "expert_group", None, "expert", None)
-    combine = constrain(combine, "expert_group", None, "expert", None)
+    with jax.named_scope("moe/dispatch"):
+        # position of each (token, choice) in its expert's queue
+        oh_flat = jax.nn.one_hot(expert_idx.reshape(nG, Gs * k), E,
+                                 dtype=jnp.int32)              # (nG,Gs*k,E)
+        if token_mask is not None:
+            # zeroed one-hots make pads rank -1 in every queue -> never kept
+            tm = jnp.repeat(token_mask.reshape(nG, Gs), k, axis=1)
+            oh_flat = oh_flat * tm[..., None].astype(jnp.int32)
+        pos_flat = jnp.cumsum(oh_flat, axis=1) * oh_flat - 1
+        pos_tok = pos_flat.max(-1).reshape(nG, Gs, k)          # (nG,Gs,k)
+        keep = (pos_tok >= 0) & (pos_tok < C)
 
-    expert_in = jnp.einsum("gsd,gsec->gecd", xg.astype(cdt), dispatch)
-    expert_in = constrain(expert_in, "expert_group", "expert", None, "embed")
+        dispatch = jnp.zeros((nG, Gs, E, C), cdt)
+        combine = jnp.zeros((nG, Gs, E, C), cdt)
+        for i in range(k):
+            ohe = jax.nn.one_hot(expert_idx[..., i], E, dtype=cdt)
+            ohc = jax.nn.one_hot(pos_tok[..., i], C, dtype=cdt)
+            sel = (ohe[..., None] * ohc[..., None, :]) \
+                * keep[..., i, None, None].astype(cdt)
+            dispatch = dispatch + sel
+            combine = combine + sel * gate_vals[..., i, None, None].astype(
+                cdt)
+        dispatch = constrain(dispatch, "expert_group", None, "expert", None)
+        combine = constrain(combine, "expert_group", None, "expert", None)
 
-    wg = nn.get_parameter_or_create(f"{name}_wi_gate", (E, d, cfg.d_ff),
-                                    I.lecun_normal())
-    wu = nn.get_parameter_or_create(f"{name}_wi_up", (E, d, cfg.d_ff),
-                                    I.lecun_normal())
-    wo = nn.get_parameter_or_create(f"{name}_wo", (E, cfg.d_ff, d),
-                                    I.scaled_normal(1.0, cfg.d_ff))
-    h = jnp.einsum("gecd,edf->gecf", expert_in, wg.astype(cdt))
-    u = jnp.einsum("gecd,edf->gecf", expert_in, wu.astype(cdt))
-    h = jax.nn.silu(h.astype(jnp.float32)).astype(cdt) * u
-    h = constrain(h, "expert_group", "expert", None, "mlp")
-    expert_out = jnp.einsum("gecf,efd->gecd", h, wo.astype(cdt))
-    expert_out = constrain(expert_out, "expert_group", "expert", None, "embed")
+        expert_in = jnp.einsum("gsd,gsec->gecd", xg.astype(cdt), dispatch)
+        expert_in = constrain(expert_in, "expert_group", "expert", None,
+                              "embed")
 
-    y = jnp.einsum("gecd,gsec->gsd", expert_out, combine)
-    y = y.reshape(B, S, d)
+    with jax.named_scope("moe/experts"):
+        wg = nn.get_parameter_or_create(f"{name}_wi_gate", (E, d, cfg.d_ff),
+                                        I.lecun_normal())
+        wu = nn.get_parameter_or_create(f"{name}_wi_up", (E, d, cfg.d_ff),
+                                        I.lecun_normal())
+        wo = nn.get_parameter_or_create(f"{name}_wo", (E, cfg.d_ff, d),
+                                        I.scaled_normal(1.0, cfg.d_ff))
+        h = jnp.einsum("gecd,edf->gecf", expert_in, wg.astype(cdt))
+        u = jnp.einsum("gecd,edf->gecf", expert_in, wu.astype(cdt))
+        h = jax.nn.silu(h.astype(jnp.float32)).astype(cdt) * u
+        h = constrain(h, "expert_group", "expert", None, "mlp")
+        expert_out = jnp.einsum("gecf,efd->gecd", h, wo.astype(cdt))
+        expert_out = constrain(expert_out, "expert_group", "expert", None,
+                               "embed")
 
-    # Switch load-balance auxiliary loss
-    me = jnp.mean(probs, axis=(0, 1))                           # (E,)
-    ce = jnp.mean(
-        jax.nn.one_hot(expert_idx[..., 0], E, dtype=jnp.float32), axis=(0, 1))
-    aux = E * jnp.sum(me * ce)
-    return constrain(y, "batch", "seq", "embed"), aux
+    with jax.named_scope("moe/combine"):
+        y = jnp.einsum("gecd,gsec->gsd", expert_out, combine)
+        y = y.reshape(B, S, d)
+        return constrain(y, "batch", "seq", "embed"), aux
 
 
 # --------------------------------------------------------------------------- #
@@ -397,14 +424,15 @@ def embed_tokens(cfg: ModelConfig, tokens):
 
 
 def lm_head(cfg: ModelConfig, x):
-    if cfg.tie_embeddings:
-        table = nn.get_parameter_or_create(
-            "tok_emb/W", (cfg.vocab_size, cfg.d_model), I.normal(0.02))
-        cdt = _ctx.get_default_context().policy.compute_dtype
-        logits = jnp.einsum("bsd,vd->bsv", x, table.astype(cdt))
-    else:
-        logits = PF.dense(x, cfg.vocab_size, name="lm_head")
-    return constrain(logits, "batch", "seq", "vocab")
+    with jax.named_scope("lm_head"):
+        if cfg.tie_embeddings:
+            table = nn.get_parameter_or_create(
+                "tok_emb/W", (cfg.vocab_size, cfg.d_model), I.normal(0.02))
+            cdt = _ctx.get_default_context().policy.compute_dtype
+            logits = jnp.einsum("bsd,vd->bsv", x, table.astype(cdt))
+        else:
+            logits = PF.dense(x, cfg.vocab_size, name="lm_head")
+        return constrain(logits, "batch", "seq", "vocab")
 
 
 def forward(cfg: ModelConfig, tokens, positions=None, last_only: bool = False):

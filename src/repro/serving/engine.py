@@ -101,6 +101,40 @@ token, decode tokens/s, prefill/decode step counts, prefix-hit tokens.
 Accessors are NaN-safe — reading ``ttft`` before the first token lands or
 ``decode_tok_per_s`` of a single-token generation returns ``nan``, never a
 garbage epoch delta or a fake 0.0.
+
+Spans and counters. Every step writes ``jax.profiler.TraceAnnotation``
+spans, which cost about a microsecond each with no profiler attached and
+land on the device's clock in a profiler trace. Each carries the stat
+``replica`` (``engine_id``: the replica index a
+:class:`~repro.serving.router.Router` gives the engine, 0 for a lone one):
+
+* ``serve.step``: one :meth:`ServingEngine.step`, whole; stats ``step``
+  (steps dispatched before this one), and once admission has run ``B``,
+  ``C`` and ``rows`` (active rows);
+* ``serve.admit``: :meth:`Scheduler.admit` and the recurrent-state reset;
+* ``serve.stage``: the step's numpy arrays, built and uploaded;
+* ``serve.dispatch``: the jitted step's call, which returns once the
+  step is enqueued;
+* ``serve.readback``: the sampled tokens' read, which waits for the device;
+* ``serve.bookkeep``: cursor advance, prompt-block registration, token
+  append, finish and the ``on_tokens`` callbacks;
+* ``serve.inbox``: :class:`~repro.serving.frontend.EngineWorker` draining
+  its submit and cancel inboxes between steps.
+
+On the device, ``jax.named_scope`` names the work of a step (op-name
+metadata such as ``.../attention/walk/paged_attention``): ``attention/qkv``,
+``attention/kv_write``, ``attention/walk``, ``attention/out``, ``mlp``,
+``moe/router``, ``moe/dispatch``, ``moe/experts``, ``moe/combine``,
+``lm_head`` and ``sample``. Parameter paths do not change.
+
+:meth:`ServingEngine.counters` gives cumulative step counters, counted on
+the host from the step's numpy arrays (no device read): ``steps``,
+``chunk_steps`` (C > 1), ``token_slots`` (B x C dispatched),
+``tokens_dispatched`` (the active rows' valid tokens, prompt and decode),
+``row_steps`` (active rows) and ``tokens_emitted``.
+:meth:`ServingEngine.metrics_summary` derives ``padding_share``,
+``chunk_step_share``, ``batch_occupancy`` and ``tokens_per_step`` from them
+(:func:`step_metrics`).
 """
 
 from __future__ import annotations
@@ -117,6 +151,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 import repro.core as nn
 from repro.core import context as _ctx
@@ -132,6 +167,25 @@ from repro.serving.scheduler import Scheduler
 # accounting) and _admit's recurrent-state reset skip — so a new leaf
 # kind can never be spilled but not reset-protected (or vice versa).
 _KV_KEYS = ("k", "v", "k_scale", "v_scale")
+
+COUNTERS = ("steps", "chunk_steps", "token_slots", "tokens_dispatched",
+            "row_steps", "tokens_emitted")
+
+
+def step_metrics(counts: dict[str, int], row_slots: int) -> dict[str, float]:
+    """What the step counters (:meth:`ServingEngine.counters`, or their
+    sum over replicas) say: the padding among the dispatched token slots,
+    the chunk-wide steps among all, the active rows among ``row_slots``
+    (steps x B), and the tokens emitted per step. Empty before the first
+    step."""
+    steps = counts["steps"]
+    if not steps:
+        return {}
+    return {"padding_share": 1.0 - counts["tokens_dispatched"]
+            / counts["token_slots"],
+            "chunk_step_share": counts["chunk_steps"] / steps,
+            "batch_occupancy": counts["row_steps"] / row_slots,
+            "tokens_per_step": counts["tokens_emitted"] / steps}
 
 
 @dataclasses.dataclass
@@ -280,6 +334,11 @@ class ServingEngine:
         # incremented by a crashing EngineWorker so the failure is visible
         # in metrics_summary even when the dead replica completed nothing
         self.worker_crashed = 0
+        # the replica index a Router gives this engine; every span says it
+        self.engine_id = 0
+        # replaced whole once per step, so a reader on another thread never
+        # sees half a step's update
+        self._counts = dict.fromkeys(COUNTERS, 0)
 
         can_page = api.prefill_paged is not None and api.cache_spec.paged
         self.paged = can_page if paged is None else (paged and can_page)
@@ -413,11 +472,13 @@ class ServingEngine:
     # ------------------------------------------------------------------ #
     def _sample_or_greedy(self, logits, temps, top_k, top_p, seeds, counts,
                           do_sample):
-        last = logits[:, -1, :].astype(jnp.float32)
-        if do_sample:
-            return sampling.sample(last, temps, top_k, top_p, seeds, counts)
-        # all-greedy batch (the default): skip the (B, V) sort pipeline
-        return jnp.argmax(last, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            last = logits[:, -1, :].astype(jnp.float32)
+            if do_sample:
+                return sampling.sample(last, temps, top_k, top_p, seeds,
+                                       counts)
+            # all-greedy batch (the default): skip the (B, V) sort pipeline
+            return jnp.argmax(last, axis=-1).astype(jnp.int32)
 
     def _env_scope(self):
         """The engine's ShardingEnv, active while building state and while
@@ -634,12 +695,13 @@ class ServingEngine:
                 lambda t, s, g, p, l: self.api.prefill_paged(
                     t, s, g, p, l, last_only=False),
                 params, tokens, state, pages, pos, length)
-        logits = logits.astype(jnp.float32)
-        if do_sample:
-            targets = sampling.sample_chunk(logits, temps, top_k, top_p,
-                                            seeds, cnt0)
-        else:
-            targets = sampling.greedy_chunk(logits)
+        with jax.named_scope("sample"):
+            logits = logits.astype(jnp.float32)
+            if do_sample:
+                targets = sampling.sample_chunk(logits, temps, top_k, top_p,
+                                                seeds, cnt0)
+            else:
+                targets = sampling.greedy_chunk(logits)
         return targets, new_state
 
     # ------------------------------------------------------------------ #
@@ -662,10 +724,32 @@ class ServingEngine:
         False when the uid is unknown (already completed — benign)."""
         return self.scheduler.cancel(uid)
 
+    def _span(self, name: str, **stats) -> TraceAnnotation:
+        return TraceAnnotation(name, replica=self.engine_id, **stats)
+
+    def counters(self) -> dict[str, int]:
+        """The cumulative step counters (see the module docstring)."""
+        return dict(self._counts)
+
+    def _count(self, C: int, length: np.ndarray, active_slots: list[int],
+               emitted: int) -> None:
+        c = self._counts
+        self._counts = {
+            "steps": c["steps"] + 1,
+            "chunk_steps": c["chunk_steps"] + (C > 1),
+            "token_slots": c["token_slots"] + self.B * C,
+            "tokens_dispatched": c["tokens_dispatched"]
+            + int(length[active_slots].sum()),
+            "row_steps": c["row_steps"] + len(active_slots),
+            "tokens_emitted": c["tokens_emitted"] + emitted}
+
     def _admit(self, now: float) -> None:
-        fresh = self.scheduler.admit(now)
-        if fresh:
+        with self._span("serve.admit"):
+            fresh = self.scheduler.admit(now)
+            if not fresh:
+                return
             idx = jnp.asarray(fresh, jnp.int32)
+
             # Zero the admitted rows of every *recurrent* state leaf so a
             # freed slot's SSM state can't leak forward (batch is axis 1,
             # see registry docstring). KV-cache leaves — _KV_KEYS, i.e.
@@ -684,88 +768,104 @@ class ServingEngine:
 
     def step(self) -> int:
         """One synchronized mixed prefill/decode step; returns #active."""
-        sched = self.scheduler
-        self._admit(time.monotonic())
-        active_slots = [s for s, r in enumerate(sched.active)
-                        if r is not None]
-        if not active_slots:
-            return 0
-        prefilling = any(len(sched.pending_prompt[s]) > 1
-                         for s in active_slots)
-        if self.spec is not None and not prefilling:
+        with self._span("serve.step", step=self._counts["steps"]) as span:
+            sched = self.scheduler
+            self._admit(time.monotonic())
+            active_slots = [s for s, r in enumerate(sched.active)
+                            if r is not None]
+            if not active_slots:
+                return 0
+            prefilling = any(len(sched.pending_prompt[s]) > 1
+                             for s in active_slots)
             # no slot is mid-prompt: run the (B, 1 + spec_k) verify step
             # instead of a (B, 1) decode step. Prefill steps stay on the
             # plain path — bitwise identical to the non-speculative engine.
-            return self._step_speculative(active_slots)
-        C = self.chunk if prefilling else 1
+            verify = self.spec is not None and not prefilling
+            C = (1 + self.spec.k if verify
+                 else self.chunk if prefilling else 1)
+            span.set_metadata(B=self.B, C=C, rows=len(active_slots))
+            if verify:
+                return self._step_speculative(active_slots)
+            return self._step_mixed(active_slots, C)
+
+    def _step_mixed(self, active_slots: list[int], C: int) -> int:
+        """One (B, C) step: prompt rows absorb up to C tokens, decode rows
+        one; rows whose prompt is done emit a token."""
+        sched = self.scheduler
         B = self.B
-        tokens = np.zeros((B, C), np.int32)
-        length = np.ones(B, np.int32)
-        temps = np.zeros(B, np.float32)
-        top_k = np.zeros(B, np.int32)
-        top_p = np.ones(B, np.float32)
-        seeds = np.zeros(B, np.int32)
-        counts = np.zeros(B, np.int32)
-        emits = [False] * B
-        prompt_done = []
-        for s in active_slots:
-            req = sched.active[s]
-            pend = sched.pending_prompt[s]
-            if pend:
-                k = min(C, len(pend))
-                for i in range(k):
-                    tokens[s, i] = pend.popleft()
-                length[s] = k
-                emits[s] = not pend   # prompt fully absorbed: sample now
-                if not pend:
-                    prompt_done.append(s)
-                req.metrics.prefill_steps += 1
-            else:
-                tokens[s, 0] = (req.generated[-1] if req.generated
-                                else (req.prompt[-1] if req.prompt else 0))
-                emits[s] = True
-                req.metrics.decode_steps += 1
-            temps[s] = req.temperature
-            top_k[s] = req.top_k
-            top_p[s] = req.top_p
-            # mask to 31 bits: callers often derive 64-bit seeds (hashes)
-            seeds[s] = (req.seed if req.seed is not None
-                        else req.uid) & 0x7FFFFFFF
-            # count = tokens generated so far: a preempted-then-resumed
-            # request keeps its generated list, so the per-(seed, count)
-            # PRNG stream continues exactly where it left off
-            counts[s] = len(req.generated)
-        do_sample = any(temps[s] > 0.0 for s in active_slots)
-        args = (self.params, jnp.asarray(tokens), self.state)
-        if self.paged:
-            args += (jnp.asarray(sched.pages),)
-        next_tok, self.state = self._step(
-            *args, jnp.asarray(sched.pos), jnp.asarray(length),
-            jnp.asarray(temps), jnp.asarray(top_k), jnp.asarray(top_p),
-            jnp.asarray(seeds), jnp.asarray(counts), do_sample=do_sample)
-        next_tok = np.asarray(next_tok)
-        now = time.monotonic()
-        if self.paged:
-            for s in prompt_done:
-                sched.register_prompt_blocks(s)
-        for s in active_slots:
-            req = sched.active[s]
-            sched.advance(s, int(length[s]))
-            if not emits[s]:
-                continue  # still absorbing prompt
-            req.generated.append(int(next_tok[s]))
-            if req.metrics.first_token_t == 0.0:
-                req.metrics.first_token_t = now
-            hit_eos = (req.eos_id is not None
-                       and req.generated[-1] == req.eos_id)
-            if (len(req.generated) >= req.max_new_tokens or hit_eos
-                    or sched.pos[s] >= self.max_seq - 1):
-                req.done = True
-                req.metrics.done_t = now
-                self.completed.append(req)
-                sched.finish(s)
-            if req.on_tokens is not None:
-                req.on_tokens(req, [req.generated[-1]], req.done)
+        with self._span("serve.stage"):
+            tokens = np.zeros((B, C), np.int32)
+            length = np.ones(B, np.int32)
+            temps = np.zeros(B, np.float32)
+            top_k = np.zeros(B, np.int32)
+            top_p = np.ones(B, np.float32)
+            seeds = np.zeros(B, np.int32)
+            counts = np.zeros(B, np.int32)
+            emits = [False] * B
+            prompt_done = []
+            for s in active_slots:
+                req = sched.active[s]
+                pend = sched.pending_prompt[s]
+                if pend:
+                    k = min(C, len(pend))
+                    for i in range(k):
+                        tokens[s, i] = pend.popleft()
+                    length[s] = k
+                    emits[s] = not pend   # prompt fully absorbed: sample now
+                    if not pend:
+                        prompt_done.append(s)
+                    req.metrics.prefill_steps += 1
+                else:
+                    tokens[s, 0] = (req.generated[-1] if req.generated
+                                    else (req.prompt[-1] if req.prompt
+                                          else 0))
+                    emits[s] = True
+                    req.metrics.decode_steps += 1
+                temps[s] = req.temperature
+                top_k[s] = req.top_k
+                top_p[s] = req.top_p
+                # mask to 31 bits: callers often derive 64-bit seeds (hashes)
+                seeds[s] = (req.seed if req.seed is not None
+                            else req.uid) & 0x7FFFFFFF
+                # count = tokens generated so far: a preempted-then-resumed
+                # request keeps its generated list, so the per-(seed, count)
+                # PRNG stream continues exactly where it left off
+                counts[s] = len(req.generated)
+            do_sample = any(temps[s] > 0.0 for s in active_slots)
+            host = ((sched.pages,) if self.paged else ()) + (
+                sched.pos, length, temps, top_k, top_p, seeds, counts)
+            tokens, meta = jnp.asarray(tokens), [jnp.asarray(a) for a in host]
+        with self._span("serve.dispatch"):
+            next_tok, self.state = self._step(
+                self.params, tokens, self.state, *meta, do_sample=do_sample)
+        with self._span("serve.readback"):
+            next_tok = np.asarray(next_tok)
+        with self._span("serve.bookkeep"):
+            now = time.monotonic()
+            if self.paged:
+                for s in prompt_done:
+                    sched.register_prompt_blocks(s)
+            emitted = 0
+            for s in active_slots:
+                req = sched.active[s]
+                sched.advance(s, int(length[s]))
+                if not emits[s]:
+                    continue  # still absorbing prompt
+                emitted += 1
+                req.generated.append(int(next_tok[s]))
+                if req.metrics.first_token_t == 0.0:
+                    req.metrics.first_token_t = now
+                hit_eos = (req.eos_id is not None
+                           and req.generated[-1] == req.eos_id)
+                if (len(req.generated) >= req.max_new_tokens or hit_eos
+                        or sched.pos[s] >= self.max_seq - 1):
+                    req.done = True
+                    req.metrics.done_t = now
+                    self.completed.append(req)
+                    sched.finish(s)
+                if req.on_tokens is not None:
+                    req.on_tokens(req, [req.generated[-1]], req.done)
+            self._count(C, length, active_slots, emitted)
         return sum(1 for r in sched.active if r is not None)
 
     def _step_speculative(self, active_slots: list[int]) -> int:
@@ -796,86 +896,95 @@ class ServingEngine:
         sched = self.scheduler
         C = 1 + self.spec.k
         B = self.B
-        tokens = np.zeros((B, C), np.int32)
-        length = np.ones(B, np.int32)
-        temps = np.zeros(B, np.float32)
-        top_k = np.zeros(B, np.int32)
-        top_p = np.ones(B, np.float32)
-        seeds = np.zeros(B, np.int32)
-        cnt0 = np.zeros(B, np.int32)
-        drafts: dict[int, list[int]] = {}
-        prompt_done = []
-        for s in active_slots:
-            req = sched.active[s]
-            pend = sched.pending_prompt[s]
-            if pend:   # single leftover prompt token: absorb, no drafts
-                tokens[s, 0] = pend.popleft()
-                drafts[s] = []
-                prompt_done.append(s)
-                req.metrics.prefill_steps += 1
-            else:
-                g = len(req.generated)
-                cap = min(self.spec.k, req.max_new_tokens - g - 1,
-                          self.max_seq - 2 - int(sched.pos[s]))
-                d = (self.spec.propose(
-                        req.prompt[: self.max_seq - 1] + req.generated, cap)
-                     if cap > 0 else [])
-                drafts[s] = d
-                tokens[s, 0] = (req.generated[-1] if req.generated
-                                else (req.prompt[-1] if req.prompt else 0))
-                for i, tok in enumerate(d):
-                    tokens[s, 1 + i] = tok
-                length[s] = 1 + len(d)
-                req.metrics.decode_steps += 1
-            temps[s] = req.temperature
-            top_k[s] = req.top_k
-            top_p[s] = req.top_p
-            seeds[s] = (req.seed if req.seed is not None
-                        else req.uid) & 0x7FFFFFFF
-            # PRNG coordinate base: position i of this row draws with
-            # count = len(generated) + i, exactly the coordinates a
-            # token-at-a-time run would use for those stream indices
-            cnt0[s] = len(req.generated)
-        do_sample = any(temps[s] > 0.0 for s in active_slots)
-        targets, self.state = self._step_spec(
-            self.params, jnp.asarray(tokens), self.state,
-            jnp.asarray(sched.pages), jnp.asarray(sched.pos),
-            jnp.asarray(length), jnp.asarray(temps), jnp.asarray(top_k),
-            jnp.asarray(top_p), jnp.asarray(seeds), jnp.asarray(cnt0),
-            do_sample=do_sample)
-        targets = np.asarray(targets)
-        now = time.monotonic()
-        for s in prompt_done:
-            sched.register_prompt_blocks(s)
-        for s in active_slots:
-            req = sched.active[s]
-            d = drafts[s]
-            t = targets[s]
-            a = 0
-            while a < len(d) and d[a] == int(t[a]):
-                a += 1
-            # accepted drafts + the target at the first divergence (when
-            # every draft matched, that's the position-after-the-last one)
-            emitted = d[:a] + [int(t[a])]
-            if req.eos_id is not None and req.eos_id in emitted:
-                emitted = emitted[: emitted.index(req.eos_id) + 1]
-            req.generated.extend(emitted)
-            kept = len(emitted) - 1
-            sched.commit_spec(s, len(d), kept)   # pos += len(emitted)
-            req.metrics.spec_proposed += len(d)
-            req.metrics.spec_accepted += kept
-            if req.metrics.first_token_t == 0.0:
-                req.metrics.first_token_t = now
-            hit_eos = (req.eos_id is not None
-                       and req.generated[-1] == req.eos_id)
-            if (len(req.generated) >= req.max_new_tokens or hit_eos
-                    or sched.pos[s] >= self.max_seq - 1):
-                req.done = True
-                req.metrics.done_t = now
-                self.completed.append(req)
-                sched.finish(s)
-            if req.on_tokens is not None:
-                req.on_tokens(req, emitted, req.done)
+        with self._span("serve.stage"):
+            tokens = np.zeros((B, C), np.int32)
+            length = np.ones(B, np.int32)
+            temps = np.zeros(B, np.float32)
+            top_k = np.zeros(B, np.int32)
+            top_p = np.ones(B, np.float32)
+            seeds = np.zeros(B, np.int32)
+            cnt0 = np.zeros(B, np.int32)
+            drafts: dict[int, list[int]] = {}
+            prompt_done = []
+            for s in active_slots:
+                req = sched.active[s]
+                pend = sched.pending_prompt[s]
+                if pend:   # single leftover prompt token: absorb, no drafts
+                    tokens[s, 0] = pend.popleft()
+                    drafts[s] = []
+                    prompt_done.append(s)
+                    req.metrics.prefill_steps += 1
+                else:
+                    g = len(req.generated)
+                    cap = min(self.spec.k, req.max_new_tokens - g - 1,
+                              self.max_seq - 2 - int(sched.pos[s]))
+                    d = (self.spec.propose(
+                            req.prompt[: self.max_seq - 1] + req.generated,
+                            cap)
+                         if cap > 0 else [])
+                    drafts[s] = d
+                    tokens[s, 0] = (req.generated[-1] if req.generated
+                                    else (req.prompt[-1] if req.prompt
+                                          else 0))
+                    for i, tok in enumerate(d):
+                        tokens[s, 1 + i] = tok
+                    length[s] = 1 + len(d)
+                    req.metrics.decode_steps += 1
+                temps[s] = req.temperature
+                top_k[s] = req.top_k
+                top_p[s] = req.top_p
+                seeds[s] = (req.seed if req.seed is not None
+                            else req.uid) & 0x7FFFFFFF
+                # PRNG coordinate base: position i of this row draws with
+                # count = len(generated) + i, exactly the coordinates a
+                # token-at-a-time run would use for those stream indices
+                cnt0[s] = len(req.generated)
+            do_sample = any(temps[s] > 0.0 for s in active_slots)
+            tokens, meta = jnp.asarray(tokens), [jnp.asarray(a) for a in (
+                sched.pages, sched.pos, length, temps, top_k, top_p, seeds,
+                cnt0)]
+        with self._span("serve.dispatch"):
+            targets, self.state = self._step_spec(
+                self.params, tokens, self.state, *meta, do_sample=do_sample)
+        with self._span("serve.readback"):
+            targets = np.asarray(targets)
+        with self._span("serve.bookkeep"):
+            now = time.monotonic()
+            for s in prompt_done:
+                sched.register_prompt_blocks(s)
+            n_emitted = 0
+            for s in active_slots:
+                req = sched.active[s]
+                d = drafts[s]
+                t = targets[s]
+                a = 0
+                while a < len(d) and d[a] == int(t[a]):
+                    a += 1
+                # accepted drafts + the target at the first divergence
+                # (when every draft matched, that's the position after the
+                # last one)
+                emitted = d[:a] + [int(t[a])]
+                if req.eos_id is not None and req.eos_id in emitted:
+                    emitted = emitted[: emitted.index(req.eos_id) + 1]
+                req.generated.extend(emitted)
+                n_emitted += len(emitted)
+                kept = len(emitted) - 1
+                sched.commit_spec(s, len(d), kept)   # pos += len(emitted)
+                req.metrics.spec_proposed += len(d)
+                req.metrics.spec_accepted += kept
+                if req.metrics.first_token_t == 0.0:
+                    req.metrics.first_token_t = now
+                hit_eos = (req.eos_id is not None
+                           and req.generated[-1] == req.eos_id)
+                if (len(req.generated) >= req.max_new_tokens or hit_eos
+                        or sched.pos[s] >= self.max_seq - 1):
+                    req.done = True
+                    req.metrics.done_t = now
+                    self.completed.append(req)
+                    sched.finish(s)
+                if req.on_tokens is not None:
+                    req.on_tokens(req, emitted, req.done)
+            self._count(C, length, active_slots, n_emitted)
         return sum(1 for r in sched.active if r is not None)
 
     def has_work(self) -> bool:
@@ -931,6 +1040,7 @@ class ServingEngine:
                 sum(1 for r in done if r.truncated)),
         }
         out.update(self.scheduler.stats())  # preemptions/requeues[/blocks]
+        out.update(step_metrics(self._counts, self._counts["steps"] * self.B))
         if self.worker_crashed:
             out["worker_crashed"] = float(self.worker_crashed)
         if self.paged:

@@ -106,6 +106,8 @@ import random
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.router import DEAD, Router
 
@@ -259,7 +261,8 @@ class EngineWorker(threading.Thread):
     def _run_loop(self) -> None:
         eng = self.engine
         while True:
-            self._drain_inbox()
+            with TraceAnnotation("serve.inbox", replica=eng.engine_id):
+                self._drain_inbox()
             if self._quarantined:
                 raise WorkerQuarantined(
                     "quarantined by the stuck-step watchdog")

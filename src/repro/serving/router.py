@@ -87,7 +87,8 @@ import random
 import time
 
 from repro.distributed.resilience import StragglerMonitor
-from repro.serving.engine import Request, ServingEngine
+from repro.serving.engine import (COUNTERS, Request, ServingEngine,
+                                  step_metrics)
 from repro.serving.paged import prefix_keys
 
 POLICIES = ("affinity", "random", "round_robin")
@@ -126,6 +127,8 @@ class Router:
                     f"{(e0.max_seq, e0.paged, e0.block_size)} — replicas "
                     f"must be interchangeable")
         self.engines = engines
+        for rid, e in enumerate(engines):
+            e.engine_id = rid      # the ``replica`` stat of its spans
         self.policy = policy
         self.imbalance = int(imbalance)
         self.max_seq = e0.max_seq
@@ -483,7 +486,8 @@ class Router:
 
     def metrics_summary(self) -> dict[str, float]:
         """Cross-replica aggregate of the engines' per-request summaries
-        (means weighted by completed-request count) plus routing stats."""
+        (means weighted by completed-request count), the step shares of
+        the engines' summed counters, and routing stats."""
         summaries = [(e.metrics_summary(), e) for e in self.engines]
         summaries = [(m, e) for m, e in summaries if m]
         out: dict[str, float] = {}
@@ -507,6 +511,10 @@ class Router:
                 s = sum(m.get(key, 0.0) for m, _ in summaries)
                 if key in summaries[0][0] or s:
                     out[key] = s
+        counts = [e.counters() for e in self.engines]
+        out.update(step_metrics(
+            {k: sum(c[k] for c in counts) for k in COUNTERS},
+            sum(c["steps"] * e.B for c, e in zip(counts, self.engines))))
         out.update(self.stats())
         return out
 

@@ -5,7 +5,9 @@ or Mosaic's lowering coverage; the TPU compiler, which ships with jaxlib,
 does — for a chip that is described, not attached. Each case lowers one
 kernel at real widths (granite-moe-1b-a400m attention: Hq 16, Hkv 8,
 D 64, block 16; mamba2-370m SSD: 32 heads, P 64, N 128, chunk 256) and
-asserts the compiled program holds a ``tpu_custom_call``. Nothing runs.
+asserts the compiled program holds a ``tpu_custom_call`` and the lowered
+module the kernel's ``name=``, which a profiler trace shows. Nothing
+runs.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, so a module-level call would
@@ -51,9 +53,10 @@ def one_chip(topo):
     return spec
 
 
-def compile_has_kernel(fn, *args):
-    text = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
+def compile_has_kernel(name, fn, *args):
+    lowered = jax.jit(fn).lower(*args)
+    assert f'kernel_name = "{name}"' in lowered.as_text()
+    assert "tpu_custom_call" in lowered.compile().as_text()
 
 
 def _pools(spec, dtype):
@@ -74,9 +77,11 @@ def test_paged_walk_compiles(one_chip, dtype, width):
     rows = one_chip((B,), jnp.int32)
     entry = pa.paged_decode if width == 1 else pa.paged_prefill
     if scale is None:
-        compile_has_kernel(lambda *a: entry(*a), q, pool, pool, pages, rows)
+        compile_has_kernel("paged_attention", lambda *a: entry(*a),
+                           q, pool, pool, pages, rows)
     else:
         compile_has_kernel(
+            "paged_attention",
             lambda q_, k, v, g, r, ks, vs: entry(q_, k, v, g, r,
                                                  k_scale=ks, v_scale=vs),
             q, pool, pool, pages, rows, scale, scale)
@@ -91,10 +96,11 @@ def test_paged_write_compiles(one_chip, dtype):
     pages = one_chip((B, MB), jnp.int32)
     pos = one_chip((B,), jnp.int32)
     if scale is None:
-        compile_has_kernel(lambda *a: pa.paged_write(*a),
+        compile_has_kernel("paged_write", lambda *a: pa.paged_write(*a),
                            pool, new, pages, pos)
     else:
         compile_has_kernel(
+            "paged_write",
             lambda p, n, g, r, s: pa.paged_write(p, n, g, r, pool_scale=s),
             pool, new, pages, pos, scale)
 
@@ -124,6 +130,7 @@ def test_tp4_sharded_walk_compiles(topo):
             q, pool, pool, pages, pos)
     text = lowered.compile().as_text()
     assert "tpu_custom_call" in text
+    assert 'kernel_name = "paged_attention"' in lowered.as_text()
     # the per-chip kernel sees its 2-head shard of the pool
     assert f"tensor<{NB}x{BS}x2x{D}xbf16>" in lowered.as_text()
 
@@ -132,7 +139,8 @@ def test_flash_attention_compiles(one_chip):
     from repro.kernels.flash_attention import flash_attention as fa
     q = one_chip((1, 512, HQ, D), jnp.bfloat16)
     kv = one_chip((1, 512, HKV, D), jnp.bfloat16)
-    compile_has_kernel(lambda *a: fa.flash_attention(*a), q, kv, kv)
+    compile_has_kernel("flash_attention", lambda *a: fa.flash_attention(*a),
+                       q, kv, kv)
 
 
 def test_flash_decode_compiles(one_chip):
@@ -140,15 +148,15 @@ def test_flash_decode_compiles(one_chip):
     q = one_chip((B, 1, HQ, D), jnp.bfloat16)
     cache = one_chip((B, 1024, HKV, D), jnp.bfloat16)
     lengths = one_chip((B,), jnp.int32)
-    compile_has_kernel(lambda *a: fa.flash_decode(*a), q, cache, cache,
-                       lengths)
+    compile_has_kernel("flash_decode", lambda *a: fa.flash_decode(*a),
+                       q, cache, cache, lengths)
 
 
 def test_ssd_compiles(one_chip):
     from repro.kernels.ssd import ssd_kernel
     Bt, S, H, Pd, G, N = 1, 1024, 32, 64, 1, 128
     compile_has_kernel(
-        lambda *a: ssd_kernel.ssd(*a, chunk=256, return_state=True),
+        "ssd", lambda *a: ssd_kernel.ssd(*a, chunk=256, return_state=True),
         one_chip((Bt, S, H, Pd), jnp.bfloat16),
         one_chip((Bt, S, H), jnp.float32),
         one_chip((H,), jnp.float32),
