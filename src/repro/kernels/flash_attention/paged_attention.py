@@ -8,22 +8,32 @@ scales with the *worst-case* table width, not the request's actual length.
 
 The kernels here never build that view. The page table and per-row
 positions ride in as scalar-prefetch operands
-(:class:`pltpu.PrefetchScalarGridSpec`), and the K/V BlockSpec index maps
-look each grid step's block id up in the table, so the Pallas pipeline
-DMAs one whole ``(block_size, Hkv, D)`` block per step — every kv head at
-once, each block read from HBM exactly once — while the next block's copy
-is in flight. Block ids past a row's last visible block are clamped to
-that last block; the pipeline skips a copy whose block index did not
-change, so the tail of a short row costs no DMA, and ``pl.when`` skips its
-FLOPs. An online softmax (m, l, acc per kv head) carried in VMEM scratch
-across the sequential kv-block grid axis reproduces the flash-attention
-recurrence.
+(:class:`pltpu.PrefetchScalarGridSpec`). One grid step attends over a KV
+tile of ``P`` pool pages, ``P * block_size`` keys (:func:`pages_per_tile`:
+the fewest pages that fill 128 lanes, at most the table's width), so the
+grid is ``(B, ceil(max_blocks / P))``. Pages are not contiguous in the
+pool, so the tile is ``P`` BlockSpec'd operands, one per page of the
+tile, each passing the pool itself: page ``p``'s index map looks column
+``kt * P + p`` up in the table, and the Pallas pipeline DMAs that whole
+``(block_size, Hkv, D)`` block — every kv head at once, each block read
+from HBM once — while the grid step before computes, the next row's first
+tile included. In VMEM the kernel stacks each kv head's slices of the
+``P`` pages into one ``(P * block_size, D)`` operand: per head one
+``(C * rep, D) x (D, P * bs)`` QK^T, the chunk-causal mask over the
+tile's key positions and one online-softmax update whose ``p @ v``
+contracts over the whole tile (m, l, acc per kv head, carried in VMEM
+scratch across the sequential tile axis). Columns past a row's last
+visible page, and past the table in a last partial tile, keep that
+page's index, so tiles past the row's end cost no DMA, and ``pl.when``
+skips their FLOPs; the keys such columns repeat are masked by position.
 
-The block is the pool's own ``(bs, Hkv, D)`` tile, so its last two block
-dims are the array's full dims (legal under the (8, 128) rule for any
-head count and head dim), and the per-head ``(bs, D)`` operands are sliced
-from VMEM, never from HBM: a one-head slice of an HBM block is a
-misaligned DMA that Mosaic rejects.
+Manual DMAs from a ``memory_space=ANY`` pool would do the same, but
+Mosaic refuses a DMA out of an HBM array whose minor dimension is under
+128 lanes (granite-moe's head dim 64), while a block whose last two dims
+are the array's full ``(Hkv, D)`` is legal for any head count and head
+dim. The per-head ``(bs, D)`` operands are sliced from VMEM, never from
+HBM: a one-head slice of an HBM block is a misaligned DMA that Mosaic
+rejects.
 
 Both entry points share one kernel:
 
@@ -46,7 +56,7 @@ pool, no materialized scatter indices). Tokens past the row's table width
 are redirected into the garbage block 0, which no valid mask ever reads.
 
 Quantized pools (int8/fp8, see :mod:`repro.kernels.quant`): the walk
-pipelines each block's ``(bs, Hkv)`` scale tile next to its K/V block and
+pipelines each page's ``(bs, Hkv)`` scale tile next to its K/V page and
 dequantizes in VMEM (one VPU broadcast multiply per head — no dequantized
 pool copy ever exists in HBM, and the DMA'd bytes are *halved*).
 :func:`paged_write` quantizes with the XLA path's own
@@ -54,8 +64,9 @@ pool copy ever exists in HBM, and the DMA'd bytes are *halved*).
 scatters the scale rows in XLA.
 
 Validated against the gather-then-dense references in interpret mode (CPU
-container, block sizes 4/8/16, GQA, ragged lengths) and compiled for TPU
-v5e at real widths (``tests/test_tpu_compile.py``). Lengths/pos semantics
+container, block sizes 4/8/16, GQA, ragged lengths on block and tile
+edges) and compiled for TPU v5e at real widths
+(``tests/test_tpu_compile.py``). Lengths/pos semantics
 assume ``lengths >= 1`` for any row whose output is consumed (the engine
 always decodes at ``pos + 1``); a length-0 row yields zeros, not the
 reference's garbage-uniform average.
@@ -76,50 +87,67 @@ from repro.kernels.flash_attention import flash_attention as fa
 from repro.kernels.flash_attention import ref
 
 
-def _paged_attn_kernel(pages_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                       bs: int, C: int, rep: int, n_kv: int, scale: float,
-                       quantized: bool):
-    """One (batch row, kv block) grid step of the paged walk.
+TILE_TOKENS = 128          # keys per grid step: the MXU's and a vreg's lanes
 
-    Scratch persists across the innermost (sequential) grid axis: m/l/acc
-    carry the online softmax of every kv head. ``pos_ref[b] + C`` is the
-    row's visible-token count — for decode (C = 1, pos = lengths - 1) that
-    is exactly ``lengths[b]``.
+
+def pages_per_tile(bs: int, MB: int) -> int:
+    """Pool pages the walk attends per grid step: the fewest whose
+    ``P * bs`` tokens fill :data:`TILE_TOKENS` lanes, never more pages
+    than the table has columns."""
+    return max(1, min(MB, -(-TILE_TOKENS // bs)))
+
+
+def _paged_attn_kernel(pages_ref, pos_ref, q_ref, *rest, bs: int, P: int,
+                       MB: int, C: int, rep: int, n_kv: int, scale: float,
+                       quantized: bool):
+    """One (batch row, kv tile) grid step of the paged walk.
+
+    A tile is ``P`` pool pages, ``P * bs`` keys; ``rest`` holds the P K
+    pages, the P V pages (then, quantized, the P K and P V scale tiles),
+    the output and the scratch. Scratch persists across the innermost
+    (sequential) grid axis: m/l/acc carry the online softmax of every kv
+    head. ``pos_ref[b] + C`` is the row's visible-token count — for
+    decode (C = 1, pos = lengths - 1) that is exactly ``lengths[b]``.
     """
     del pages_ref
+    k_pages, v_pages = rest[:P], rest[P:2 * P]
     if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        o_ref, m_scr, l_scr, acc_scr = rest
+        ks_pages, vs_pages = rest[2 * P:3 * P], rest[3 * P:4 * P]
+    o_ref, m_scr, l_scr, acc_scr = rest[-4:]
     b = pl.program_id(0)
-    ki = pl.program_id(1)
-    nk = pl.num_programs(1)
+    kt = pl.program_id(1)
+    nt = pl.num_programs(1)
+    tile = P * bs
     visible = pos_ref[b] + C          # tokens any query of this row can see
 
-    @pl.when(ki == 0)
+    @pl.when(kt == 0)
     def _init():
         fa.init_carry(m_scr, l_scr, acc_scr)
 
-    @pl.when(ki * bs < visible)
+    @pl.when(kt * tile < visible)
     def _body():
         # chunk-causal: query i (s-row i * rep + r) sees kpos <= pos + i
         qpos = pos_ref[b] + jax.lax.broadcasted_iota(
-            jnp.int32, (C * rep, bs), 0) // rep
-        kpos = ki * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (C * rep, bs), 1)
-        causal = kpos <= qpos
+            jnp.int32, (C * rep, tile), 0) // rep
+        kpos = kt * tile + jax.lax.broadcasted_iota(
+            jnp.int32, (C * rep, tile), 1)
+        mask = kpos <= qpos
+        if MB % P:                    # the last tile runs past the table
+            mask &= kpos < MB * bs
         for h in range(n_kv):
+            # kv head h of the tile's pages, stacked in VMEM: (tile, D)
             q = q_ref[0, h].astype(jnp.float32)               # (C*rep, D)
-            k = k_ref[0, :, h, :].astype(jnp.float32)         # (bs, D)
-            v = v_ref[0, :, h, :].astype(jnp.float32)
+            k = jnp.concatenate([r[0, :, h, :] for r in k_pages])
+            v = jnp.concatenate([r[0, :, h, :] for r in v_pages])
+            k, v = k.astype(jnp.float32), v.astype(jnp.float32)
             if quantized:
                 # dequant in VMEM: one broadcast multiply per tile
-                k = k * ks_ref[0, :, h:h + 1]
-                v = v * vs_ref[0, :, h:h + 1]
-            fa.online_softmax_step(q, k, v, causal, scale, m_scr.at[h],
+                k = k * jnp.concatenate([r[0, :, h:h + 1] for r in ks_pages])
+                v = v * jnp.concatenate([r[0, :, h:h + 1] for r in vs_pages])
+            fa.online_softmax_step(q, k, v, mask, scale, m_scr.at[h],
                                    l_scr.at[h], acc_scr.at[h])
 
-    @pl.when(ki == nk - 1)
+    @pl.when(kt == nt - 1)
     def _fini():
         for h in range(n_kv):
             o_ref[0, h] = fa.carry_output(l_scr.at[h], acc_scr.at[h],
@@ -130,8 +158,8 @@ def _paged_walk(q, k_pool, v_pool, pages, pos, *, scale, interpret,
                 k_scale=None, v_scale=None):
     """Shared pallas_call builder: q (B, C, Hq, D) through the page table
     with the chunk-causal mask anchored at per-row ``pos``. Quantized
-    pools (int8/fp8) pass their (NB, bs, Hkv) scale arrays as extra
-    pipelined operands."""
+    pools (int8/fp8) pass their (NB, bs, Hkv) scale arrays, which ride
+    the pipeline page by page next to K and V."""
     B, C, Hq, D = q.shape
     _, bs, Hkv, _ = k_pool.shape
     MB = pages.shape[1]
@@ -142,32 +170,33 @@ def _paged_walk(q, k_pool, v_pool, pages, pos, *, scale, interpret,
         raise ValueError("k_scale given without v_scale")
     rep = Hq // Hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
+    P = pages_per_tile(bs, MB)
     # head-major q: (B, Hkv, C * rep, D), row c * rep + r
     qh = (q.reshape(B, C, Hkv, rep, D).transpose(0, 2, 1, 3, 4)
           .reshape(B, Hkv, C * rep, D))
 
-    def block_map(b, ki, pages_ref, pos_ref):
-        # the page-table lookup; steps past the row's last visible block
-        # keep that block's index, so the pipeline issues no new copy
-        last = jnp.maximum(pos_ref[b] + C - 1, 0) // bs
-        col = jnp.minimum(jnp.minimum(ki, last), MB - 1)
-        return pages_ref[b, col], 0, 0, 0
-
-    def scale_map(b, ki, pages_ref, pos_ref):
-        return block_map(b, ki, pages_ref, pos_ref)[:3]
+    def page_spec(p, block):
+        """Page p of every tile, looked up in the table. Columns past the
+        row's last visible page (or past the table) keep that page's
+        index, so the tiles past a row's end cost no copy."""
+        def index(b, kt, pages_ref, pos_ref):
+            last = jnp.maximum(pos_ref[b] + C - 1, 0) // bs
+            col = jnp.minimum(jnp.minimum(kt * P + p, last), MB - 1)
+            return (pages_ref[b, col],) + (0,) * (len(block) - 1)
+        return pl.BlockSpec(block, index)
 
     row_spec = pl.BlockSpec((1, Hkv, C * rep, D),
-                            lambda b, ki, *_: (b, 0, 0, 0))
-    kv_spec = pl.BlockSpec((1, bs, Hkv, D), block_map)
-    in_specs = [row_spec, kv_spec, kv_spec]
-    operands = [pages, jnp.asarray(pos, jnp.int32), qh, k_pool, v_pool]
+                            lambda b, kt, *_: (b, 0, 0, 0))
+    kv_specs = [page_spec(p, (1, bs, Hkv, D)) for p in range(P)]
+    in_specs = [row_spec] + 2 * kv_specs
+    operands = [pages, jnp.asarray(pos, jnp.int32), qh]
+    operands += P * [k_pool] + P * [v_pool]
     if quantized:
-        sc_spec = pl.BlockSpec((1, bs, Hkv), scale_map)
-        in_specs += [sc_spec, sc_spec]
-        operands += [k_scale, v_scale]
+        in_specs += 2 * [page_spec(p, (1, bs, Hkv)) for p in range(P)]
+        operands += P * [k_scale] + P * [v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,            # pages, pos
-        grid=(B, MB),
+        grid=(B, pl.cdiv(MB, P)),
         in_specs=in_specs,
         out_specs=row_spec,
         scratch_shapes=[
@@ -177,8 +206,9 @@ def _paged_walk(q, k_pool, v_pool, pages, pos, *, scale, interpret,
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_attn_kernel, bs=bs, C=C, rep=rep, n_kv=Hkv,
-                          scale=scale, quantized=quantized),
+        functools.partial(_paged_attn_kernel, bs=bs, P=P, MB=MB, C=C,
+                          rep=rep, n_kv=Hkv, scale=scale,
+                          quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, C * rep, D), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -71,3 +71,14 @@ def _fresh_registry():
     nn.clear_parameters()
     yield
     nn.clear_parameters()
+
+
+@pytest.fixture(autouse=True)
+def _restore_default_context():
+    """A test that sets the process-wide default context (the benchmark
+    harness sets ``pure_bf16``) leaves the next test, which may be another
+    file's in the same pytest-xdist worker, the context it found."""
+    from repro.core import context as ctx
+    saved = ctx.get_default_context()
+    yield
+    ctx.set_default_context(saved)

@@ -3,9 +3,10 @@
 Everything runs the kernels in interpret mode (CPU container): same kernel
 logic as the compiled TPU build, minus Mosaic. Sweeps cover block sizes
 {4, 8, 16}, GQA ratios (incl. MQA), ragged lengths exactly on / one off
-block boundaries, all-idle rows, the fused scatter (incl. the overrun ->
-garbage-block regression), the dense-prefill-as-paged-walk route, and an
-engine-level smoke with ``kernels="pallas_interpret"``.
+block boundaries and multi-page tile edges, all-idle rows, the tile rule,
+the fused scatter (incl. the overrun -> garbage-block regression), the
+dense-prefill-as-paged-walk route, and an engine-level smoke with
+``kernels="pallas_interpret"``.
 """
 
 import dataclasses
@@ -139,6 +140,64 @@ def test_dense_prefill_routes_through_paged_walk():
         got = ops.attention_prefill(q, kc, vc, pos)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-5, rtol=1e-5)
+
+
+# ---------------------------------------------------------------------- #
+# multi-page tiles
+# ---------------------------------------------------------------------- #
+
+@pytest.mark.parametrize("bs,MB,P", [
+    (4, 80, 32), (8, 40, 16), (16, 528, 8),   # P * bs = 128
+    (16, 3, 3), (8, 1, 1),                    # capped at the table width
+    (48, 10, 3), (1, 300, 128),               # bs not dividing 128
+    (128, 8, 1), (64, 2, 2)])                 # prefill_dense's bs
+def test_pages_per_tile(bs, MB, P):
+    assert pa.pages_per_tile(bs, MB) == P
+    assert 1 <= P <= MB
+    assert P * bs >= pa.TILE_TOKENS or P == MB
+
+
+def tile_edge_case(bs):
+    """A table of 2.5 tiles (MB not a multiple of P) and rows ending on a
+    tile edge, one short of it, at 1, mid-tile past an edge, at the
+    table's end and 3 past it (a padded chunk overrunning the table),
+    plus an idle row (all-zero table). Returns the table's width and the
+    rows' visible lengths."""
+    P = pa.pages_per_tile(bs, 10 ** 6)
+    tile = P * bs
+    MB = 2 * P + P // 2
+    visible = [tile, tile - 1, 1, tile + 3, MB * bs, MB * bs + 3, 1]
+    return MB, visible
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("C", [1, 5], ids=["decode", "prefill"])
+@pytest.mark.parametrize("bs", [4, 8, 16])
+def test_paged_walk_tile_edges(bs, C, dtype):
+    """Walks of several multi-page tiles against the gather-then-dense
+    references. With C = 5 the row ending at ``tile + 3`` has its causal
+    diagonal cross the first tile edge."""
+    B, Hq, Hkv, D = 7, 8, 2, 32
+    MB, visible = tile_edge_case(bs)
+    kp, vp, pages = make_pools(B, MB, bs, Hkv, D, seed=70 + bs)
+    kp, vp = kp.astype(dtype), vp.astype(dtype)
+    pages = pages.at[B - 1, :].set(0)                 # last row idle
+    q = rand((B, C, Hq, D), 71, dtype)
+    if C == 1:
+        lengths = jnp.asarray(visible, jnp.int32)
+        got = pa.paged_decode(q, kp, vp, pages, lengths, interpret=True)
+        want = fa_ref.paged_decode_reference(q, kp, vp, pages, lengths)
+    else:
+        # the first row's chunk ends on the tile edge; the 1-token row is
+        # a fresh prompt, its chunk sees 1..C tokens
+        pos = jnp.asarray([max(v - C, 0) for v in visible], jnp.int32)
+        got = pa.paged_prefill(q, kp, vp, pages, pos, interpret=True)
+        want = fa_ref.paged_prefill_reference(q, kp, vp, pages, pos)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
 
 
 # ---------------------------------------------------------------------- #

@@ -149,6 +149,40 @@ def test_paged_prefill_quant_parity(bs, C, qdtype):
                                atol=1e-5, rtol=1e-5)
 
 
+@pytest.mark.parametrize("qdtype", QDTYPES, ids=QIDS)
+@pytest.mark.parametrize("C", [1, 5], ids=["decode", "prefill"])
+@pytest.mark.parametrize("bs", [4, 8, 16])
+def test_paged_walk_quant_tile_edges(bs, C, qdtype):
+    """Quantized walks of several multi-page tiles (MB not a multiple of
+    the tile's pages): the scale tiles ride with their pages, rows end on
+    a tile edge, one short of it, at 1, past an edge, at the table's end
+    and past it, one row idle."""
+    B, Hq, Hkv, D = 7, 8, 2, 32
+    P = pa.pages_per_tile(bs, 10 ** 6)
+    tile = P * bs
+    MB = 2 * P + P // 2
+    visible = [tile, tile - 1, 1, tile + 3, MB * bs, MB * bs + 3, 1]
+    _, (kq, ks, vq, vs), pages = make_qpools(B, MB, bs, Hkv, D, qdtype,
+                                             seed=80 + bs)
+    pages = pages.at[B - 1, :].set(0)                 # last row idle
+    q = rand((B, C, Hq, D), 81)
+    scales = dict(k_scale=ks, v_scale=vs)
+    if C == 1:
+        lengths = jnp.asarray(visible, jnp.int32)
+        got = pa.paged_decode(q, kq, vq, pages, lengths, interpret=True,
+                              **scales)
+        want = fa_ref.paged_decode_reference(q, kq, vq, pages, lengths,
+                                             **scales)
+    else:
+        pos = jnp.asarray([max(v - C, 0) for v in visible], jnp.int32)
+        got = pa.paged_prefill(q, kq, vq, pages, pos, interpret=True,
+                               **scales)
+        want = fa_ref.paged_prefill_reference(q, kq, vq, pages, pos,
+                                              **scales)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
+
+
 def test_ops_dispatch_modes_agree_quant():
     """All three CPU-runnable modes through the ops layer, same result."""
     B, bs, MB, Hq, Hkv, D = 2, 8, 4, 4, 2, 32

@@ -4,7 +4,9 @@ Interpret mode never checks the TPU's (8, 128) tiling rules, VMEM limits
 or Mosaic's lowering coverage; the TPU compiler, which ships with jaxlib,
 does — for a chip that is described, not attached. Each case lowers one
 kernel at real widths (granite-moe-1b-a400m attention: Hq 16, Hkv 8,
-D 64, block 16; mamba2-370m SSD: 32 heads, P 64, N 128, chunk 256) and
+D 64, block 16; the paged walk also at mistral-nemo-12b's Hq 32, Hkv 8,
+D 128 and the benchmark cell's 8 x 256 chunk over a 528-column table;
+mamba2-370m SSD: 32 heads, P 64, N 128, chunk 256) and
 asserts the compiled program holds a ``tpu_custom_call`` and the lowered
 module the kernel's ``name=``, which a profiler trace shows. Nothing
 runs.
@@ -66,20 +68,33 @@ def _pools(spec, dtype):
     return pool, scale
 
 
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8],
-                         ids=["bf16", "int8"])
-@pytest.mark.parametrize("width", [1, C], ids=["decode", "prefill"])
-def test_paged_walk_compiles(one_chip, dtype, width):
+# mistral-nemo-12b attention at the benchmark cell's shape: 8 rows, chunk
+# 256, a 528-column page table (max_seq 8448 / block 16), 4225 blocks
+MISTRAL = dict(B=8, HQ=32, HKV=8, D=128, BS=16, MB=528, NB=4225)
+GRANITE = dict(B=B, HQ=HQ, HKV=HKV, D=D, BS=BS, MB=MB, NB=NB)
+
+
+@pytest.mark.parametrize("dtype,width,shape", [
+    pytest.param(jnp.bfloat16, 1, GRANITE, id="decode-bf16"),
+    pytest.param(jnp.bfloat16, C, GRANITE, id="prefill-bf16"),
+    pytest.param(jnp.int8, 1, GRANITE, id="decode-int8"),
+    pytest.param(jnp.int8, C, GRANITE, id="prefill-int8"),
+    pytest.param(jnp.bfloat16, 1, MISTRAL, id="mistral-decode-bf16"),
+    pytest.param(jnp.bfloat16, 256, MISTRAL, id="mistral-prefill-bf16"),
+])
+def test_paged_walk_compiles(one_chip, dtype, width, shape):
     from repro.kernels.flash_attention import paged_attention as pa
-    pool, scale = _pools(one_chip, dtype)
-    q = one_chip((B, width, HQ, D), jnp.bfloat16)
-    pages = one_chip((B, MB), jnp.int32)
-    rows = one_chip((B,), jnp.int32)
+    s = shape
+    pool = one_chip((s["NB"], s["BS"], s["HKV"], s["D"]), dtype)
+    q = one_chip((s["B"], width, s["HQ"], s["D"]), jnp.bfloat16)
+    pages = one_chip((s["B"], s["MB"]), jnp.int32)
+    rows = one_chip((s["B"],), jnp.int32)
     entry = pa.paged_decode if width == 1 else pa.paged_prefill
-    if scale is None:
+    if dtype == jnp.bfloat16:
         compile_has_kernel("paged_attention", lambda *a: entry(*a),
                            q, pool, pool, pages, rows)
     else:
+        scale = one_chip((s["NB"], s["BS"], s["HKV"]), jnp.float32)
         compile_has_kernel(
             "paged_attention",
             lambda q_, k, v, g, r, ks, vs: entry(q_, k, v, g, r,
