@@ -25,7 +25,7 @@ import time
 import numpy as np
 
 from harness import check, spec, traffic
-from harness.model import Model, make_params
+from harness.model import for_cell, make_params
 from harness.record import StepRecorder
 
 
@@ -47,7 +47,7 @@ class Record:
 class RunData:
     """What a per-layer metric's reader may read."""
     cell: dict
-    model: Model
+    model: object               # archs/<model_type>.py's Model
     peaks: object               # harness.peaks.Peaks
     chips: int
     devices: list[str]          # device id of each replica, in order
@@ -87,7 +87,7 @@ def setup_context():
         jax.default_backend(), type_config="pure_bf16"))
 
 
-def build_engines(cell: dict, m: Model, params):
+def build_engines(cell: dict, m, params):
     """The cell's engines, and a router in front of them when the cell has
     several replicas. Kernels follow the platform (Pallas on a TPU)."""
     import jax.numpy as jnp
@@ -110,7 +110,7 @@ def build_engines(cell: dict, m: Model, params):
                            step_deadline_s=float("inf"))
 
 
-def check_params(m: Model, params) -> None:
+def check_params(m, params) -> None:
     """The benchmark's leaves are exactly the program's: same names, shapes
     and dtypes."""
     import jax
@@ -183,7 +183,7 @@ def load_reader(name: str):
 class Client:
     """Sends the cell's traffic and stamps what comes back."""
 
-    def __init__(self, cell, m: Model, seed: int, workers, router):
+    def __init__(self, cell, m, seed: int, workers, router):
         self.workers, self.router = workers, router
         self.load, self.mix = cell["load"], cell["mix"]
         self.m, self.seed = m, seed
@@ -316,7 +316,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, *,
 
     setup_context()
     compiles = CompileCounter()
-    m = Model.from_config(cell["config"], cell["model"])
+    m = for_cell(cell)
     chips = cell["chips"]
     devices = jax.devices()[:chips]
     kind = devices[0].device_kind

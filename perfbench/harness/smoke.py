@@ -1,23 +1,27 @@
 """A cell cut to a size the CPU runs in seconds, for rehearsals and tests.
 
 Same keys, same code path; every width, length and rate scaled down. Its
-numbers mean nothing about the chip.
+numbers mean nothing about the chip. The model's own keys are cut by its
+architecture module (``smoke_cut``).
 """
 
 from __future__ import annotations
 
 import copy
 
+from harness.model import arch
+
+
+def smoke_config(config: dict) -> dict:
+    """A copy of the configuration ``config`` cut to smoke size."""
+    c = copy.deepcopy(config)
+    arch(c["model_type"]).smoke_cut(c)
+    return c
+
 
 def smoke_cell(cell: dict) -> dict:
     c = copy.deepcopy(cell)
-    model = c["model"]
-    model.update(hidden_size=128, intermediate_size=128, num_hidden_layers=2,
-                 num_attention_heads=4, num_key_value_heads=2, head_dim=32,
-                 vocab_size=512, attention_multiplier=32 ** -0.5)
-    if model.get("num_local_experts"):
-        model.update(num_local_experts=4, num_experts_per_tok=2,
-                     moe_group_size=64)
+    c["model"] = smoke_config(c["model"])
     c["engine"].update(max_batch=4, chunk=8, max_seq=256)
     mix = c["mix"]
     if mix["kind"] == "requests":

@@ -8,10 +8,12 @@ The program names none of its Pallas calls, so the walk is known by what
 it is: among a step's Pallas calls (``tpu_custom_call``), the one that takes
 the page table (``s32[B,max_blocks]``, the shape the step was given) and
 aliases no operand to its output, as the paged writes alias the pool they
-update. A step has to run exactly that one program once per layer. Any
-other Pallas call that aliases nothing, or a walk that aliases, or a count
-other than one per layer, raises: the reader would otherwise time another
-kernel, or none, without a word. A step with no Pallas call (kernels other
+update. A step has to run as many such calls, of as many distinct
+programs, as the model's architecture module says (``walk_calls``: one
+program once per layer for every architecture so far). Any other Pallas
+call that aliases nothing, or a walk that aliases, or another count of
+calls or programs, raises: the reader would otherwise time another kernel,
+or none, without a word. A step with no Pallas call (kernels other
 than Pallas) has nothing to read."""
 
 import re
@@ -37,12 +39,13 @@ def walk_ns(events, step, model) -> int:
     walk = [e for e in pallas if ALIASES not in e[2] and table in e[2]]
     stray = [e for e in pallas if ALIASES not in e[2] and table not in e[2]]
     names = sorted({_name(e[2]) for e in walk})
-    if stray or len(names) != 1 or len(walk) != model.layers:
+    calls, programs = model.walk_calls()
+    if stray or len(names) != programs or len(walk) != calls:
         raise ValueError(
-            f"step {step.step}: the paged walk is not one Pallas call per "
-            f"layer that takes the {table} page table and aliases nothing: "
-            f"{len(walk)} such calls ({names}) for {model.layers} layers, "
-            f"and {len(stray)} other unaliased Pallas calls "
+            f"step {step.step}: the paged walk is not {calls} Pallas calls "
+            f"of {programs} program(s) that take the {table} page table and "
+            f"alias nothing: {len(walk)} such calls ({names}), and "
+            f"{len(stray)} other unaliased Pallas calls "
             f"({sorted({_name(e[2]) for e in stray})})")
     return sum(d for _, d, _ in walk)
 

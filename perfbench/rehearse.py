@@ -61,7 +61,7 @@ def aot(names: list[str]) -> None:
     from jax.sharding import SingleDeviceSharding
 
     from harness import drive, spec
-    from harness.model import Model, global_leaves, layer_leaves
+    from harness.model import for_cell
     from repro.models.registry import get_model
     from repro.serving.engine import ServingEngine
 
@@ -75,15 +75,13 @@ def aot(names: list[str]) -> None:
 
     for name in cells(names):
         cell = spec.load_cell(name)
-        m = Model.from_config(cell["config"], cell["model"])
+        m = for_cell(cell)
         api = get_model(m.to_repro())
         e = cell["engine"]
         B, C0, bs = e["max_batch"], e["chunk"], 16
         mb = math.ceil((e["max_seq"] + C0) / bs)
-        params = {n: sds((m.layers,) + s, dt)
-                  for n, (s, dt, _) in layer_leaves(m).items()}
-        params.update({n: sds(s, dt)
-                       for n, (s, dt, _) in global_leaves(m).items()})
+        params = {n: sds(((depth,) if depth else ()) + s, dt)
+                  for n, (depth, s, dt, _) in m.leaves().items()}
         state = jax.tree.map(
             lambda a: sds(a.shape, a.dtype),
             api.paged_state_specs(B, B * mb + 1, bs, jnp.bfloat16))

@@ -46,7 +46,7 @@ def main(argv=None) -> int:
     import numpy as np
 
     from harness import drive, spec
-    from harness.model import Model, make_params
+    from harness.model import for_cell, make_params
     from repro.launch.cache import enable_compile_cache
     from repro.serving.frontend import EngineWorker
 
@@ -57,7 +57,7 @@ def main(argv=None) -> int:
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     drive.setup_context()
-    m = Model.from_config(cell["config"], cell["model"])
+    m = for_cell(cell)
     engines, router = drive.build_engines(cell, m, make_params(m, args.seed))
     for e in engines:
         drive.warm(e, drive.warm_shapes(cell))

@@ -5,12 +5,12 @@ import json
 import pytest
 
 from harness import counts, peaks
-from harness.model import Model, param_count
+from harness.model import param_count, parse
 from harness.spec import BENCH
 
 
 def model(name):
-    return Model.from_config(
+    return parse(
         name, json.loads((BENCH / "configs" / f"{name}.json").read_text()))
 
 
@@ -34,10 +34,10 @@ def test_token_flops_by_hand():
     # q 5120x4096, k and v 5120x1024, o 4096x5120, three 5120x14336 matmuls
     attn = 2 * (5120 * 4096 + 2 * 5120 * 1024 + 4096 * 5120)
     mlp = 2 * 3 * 5120 * 14336
-    assert counts.token_flops(m) == attn + mlp
+    assert m.token_flops(0) == attn + mlp
     g = model("granite-moe-1b-a400m")
     # router 1024x32, 8 of 32 experts of three 1024x512 matmuls
-    assert counts.token_flops(g) == (
+    assert g.token_flops(0) == (
         2 * (1024 * 1024 + 2 * 1024 * 512 + 1024 * 1024)
         + 2 * 1024 * 32 + 8 * 2 * 3 * 1024 * 512)
 
@@ -48,12 +48,12 @@ def test_step_flops_counts_valid_tokens_context_and_emitting_heads():
     # keys) that emits
     rows = [(9, 1, True), (0, 3, True)]
     assert counts.visible_pairs(rows) == 10 + 6
-    want = 10 * (4 * counts.token_flops(m) + 4 * 32 * 128 * 16) \
+    want = 10 * (4 * m.token_flops(0) + 4 * 32 * 128 * 16) \
         + 2 * 2 * 5120 * 131072
     assert counts.step_flops(m, rows) == want
     # a mid-prompt row emits nothing: no head
     assert counts.step_flops(m, [(0, 3, False)]) == \
-        10 * (3 * counts.token_flops(m) + 4 * 32 * 128 * 6)
+        10 * (3 * m.token_flops(0) + 4 * 32 * 128 * 6)
 
 
 def test_paged_attention_cost_by_hand():

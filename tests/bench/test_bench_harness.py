@@ -13,9 +13,10 @@ import time
 import pytest
 
 from harness import check, drive, spec
-from harness.model import Model, make_params
+from harness.model import for_cell, make_params
 from harness.record import StepRecorder
 from harness.smoke import smoke_cell
+from planted import alter_tokens, keep_state
 
 REPO = spec.REPO
 SEED = 2**32 + 11
@@ -28,40 +29,39 @@ def smoke(name):
     return cell
 
 
+def first_finished(cell, seed, records, w1):
+    """The first greedy requests the window finished, in the order they
+    were sent, as many as the cell checks."""
+    fin = [r for r in check.finished(records, w1)
+           if len(r.req.generated) == r.spec.max_new]
+    return sorted(fin, key=lambda r: r.req.uid)[:cell["check"]["sample"]]
+
+
+def scripted(name, monkeypatch):
+    """The smoke cell as a fixed script, whatever the CPU's load: one client
+    sends the cell's requests one after another, so every step has one row
+    and the same shape in every run, and the check reads the first two
+    greedy requests it finished rather than a sample of however many
+    finished in the window. (A sample can reach a request whose bfloat16
+    near-tie reads 0.06 at smoke width, whatever the step shapes.)"""
+    cell = smoke(name)
+    cell["load"] = {"mode": "closed", "clients": 1, "requests_per_client": 400,
+                    "preroll_s": 1.0}
+    cell["check"]["sample"] = 2
+    monkeypatch.setattr(check, "sample", first_finished)
+    return cell
+
+
 @pytest.mark.parametrize("name", ["granite-moe.chat", "mistral-nemo.longdoc"])
-def test_sound_run_is_correct(name):
-    res = drive.run(smoke(name), SEED, 2.0, False, t_start=time.perf_counter())
+def test_sound_run_is_correct(name, monkeypatch):
+    res = drive.run(scripted(name, monkeypatch), SEED, 3.0, False,
+                    t_start=time.perf_counter())
     assert res["correct"], res["checks"]
     assert res["attempted"] > 0 and res["failed"] == 0
     assert set(res["metrics"]) == set(spec.load_cell(name)["end_to_end"])
     assert res["metrics"]["output_tokens_per_s"]["value"] > 0
     assert res["checks"]["max_logit_gap"]["value"] <= 0.05
     assert res["checks"]["compiles_in_window"]["value"] == 0
-
-
-def alter_tokens(engines):
-    """A fault planted in the timed path: every sampled token is moved to
-    the next vocabulary id where the step produces it."""
-    for e in engines:
-        orig = e._step
-        vocab = e.api.cfg.vocab_size
-
-        def step(*args, _orig=orig, **kw):
-            tok, state = _orig(*args, **kw)
-            return (tok + 1) % vocab, state
-        e._step = step
-
-
-def keep_state(engines):
-    """A fault planted in the timed path: the step returns the state it was
-    given, so no step's keys and values reach the cache."""
-    for e in engines:
-        orig = e._step
-
-        def step(params, tokens, state, *rest, _orig=orig, **kw):
-            tok, _ = _orig(params, tokens, state, *rest, **kw)
-            return tok, state
-        e._step = step
 
 
 def test_altered_tokens_are_not_correct():
@@ -87,7 +87,7 @@ def test_float8_control_reads_wider_than_the_program():
     from repro.serving.engine import Request
     cell = smoke("granite-moe.chat")
     drive.setup_context()
-    m = Model.from_config(cell["config"], cell["model"])
+    m = for_cell(cell)
     engines, _ = drive.build_engines(cell, m, make_params(m, SEED))
     eng = engines[0]
     reqs = [Request(uid=i, prompt=list(range(3 + i, 40 + 7 * i)),
@@ -103,11 +103,11 @@ def test_float8_control_reads_wider_than_the_program():
     assert not check.judge(r["control"], 0, limit)
 
 
-def test_control_run_reports_the_control_not_correct():
+def test_control_run_reports_the_control_not_correct(monkeypatch):
     """A whole run with the control on: the program's run is correct and
     the control's own verdict, by the same comparison, is not."""
-    res = drive.run(smoke("mistral-nemo.longdoc"), SEED, 2.0, False,
-                    t_start=time.perf_counter(), control=True)
+    res = drive.run(scripted("mistral-nemo.longdoc", monkeypatch), SEED, 3.0,
+                    False, t_start=time.perf_counter(), control=True)
     assert res["correct"], res["checks"]
     assert res["control"]["correct"] is False
     assert res["control"]["max_logit_gap"]["value"] > \
@@ -121,7 +121,7 @@ def test_step_record_reads_what_the_step_dispatches():
     from repro.serving.engine import Request
     cell = smoke("mistral-nemo.longdoc")
     drive.setup_context()
-    m = Model.from_config(cell["config"], cell["model"])
+    m = for_cell(cell)
     engines, _ = drive.build_engines(cell, m, make_params(m, SEED))
     eng = engines[0]
     rec = StepRecorder(engines)

@@ -6,7 +6,9 @@ import types
 
 import pytest
 
-from harness import drive, layers, trace
+from harness import drive, layers, spec, trace
+from harness.model import parse
+from harness.smoke import smoke_config
 from harness.spec import BENCH
 
 # two overlapping ops, a gap, one op; a kernel among them
@@ -31,7 +33,15 @@ WRITE = ('%closed_call.7 = bf16[9,16,1,64]{3,2,1,0} custom-call(s32[2,5]{1,0} '
          '%p, bf16[9,16,1,64]{3,2,1,0} %k), custom_call_target="tpu_custom_call", '
          'output_to_operand_aliasing={{}: (1, {})}')
 STEP = types.SimpleNamespace(step=0, pages=(2, 5))
-TWO_LAYERS = types.SimpleNamespace(layers=2)
+
+
+def model(name, smoke=False):
+    c = spec.load("configs", name)
+    return parse(name, smoke_config(c) if smoke else c)
+
+
+# the mistral architecture at smoke size: two layers, one walk per layer
+TWO_LAYERS = model("mistral-nemo-12b-pp4", smoke=True)
 
 
 def walk_ns():
@@ -53,11 +63,13 @@ def test_kernel_time_by_name():
 
 @pytest.mark.parametrize("evs", [
     [[5, 7, WALK]],                                   # one walk, two layers
+    [[5, 7, WALK], [17, 7, WALK], [29, 7, WALK]],     # three, two layers
     [[5, 7, WALK], [17, 7, WALK.replace("closed_call.9", "closed_call.8")]],
     [[5, 7, WALK], [17, 7, WALK], [30, 2, WALK.replace("s32[2,5]", "s32[4]")]],
     [[0, 5, WRITE], [5, 7, WALK + ", output_to_operand_aliasing={{}: (2, {})}"]],
-], ids=["count", "two-programs", "stray-kernel", "walk-aliases"])
+], ids=["count", "one-too-many", "two-programs", "stray-kernel", "walk-aliases"])
 def test_walk_reader_refuses_what_it_does_not_know(evs):
+    assert TWO_LAYERS.walk_calls() == (2, 1)
     with pytest.raises(ValueError, match="paged walk"):
         walk_ns()(evs, STEP, TWO_LAYERS)
 
@@ -107,7 +119,7 @@ def test_recorded_trace():
     w = layers.window_ns(run)
     # one walk per layer in each of the two steps: 24 layers, a (32, 132)
     # page table
-    granite = types.SimpleNamespace(layers=24)
+    granite = model("granite-moe-1b-a400m")
     got = [walk_ns()(trace.in_span(evs, sp["start"], sp["end"]),
                      types.SimpleNamespace(step=sp["step"], pages=(32, 132)),
                      granite)

@@ -5,7 +5,7 @@ import time
 import numpy as np
 
 from harness import drive, spec, traffic
-from harness.model import Model
+from harness.model import for_cell
 
 SEED = 2**33 + 5          # seeds may exceed 32 bits
 
@@ -96,7 +96,7 @@ class FakeWorker:
 def client(name, **load):
     cell = spec.load_cell(name)
     cell["load"].update(load)
-    m = Model.from_config(cell["config"], cell["model"])
+    m = for_cell(cell)
     w = FakeWorker()
     return drive.Client(cell, m, SEED, [w], None), w
 
